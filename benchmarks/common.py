@@ -1,7 +1,6 @@
 """Shared benchmark plumbing: trace construction, backend sweep, CSV rows."""
 from __future__ import annotations
 
-import time
 from typing import Dict, List
 
 from repro.configs import get_config
@@ -51,11 +50,3 @@ class Csv:
         print("name,us_per_call,derived")
         for r in self.rows:
             print(r)
-
-
-def timed(fn, *args, repeat: int = 3, **kw):
-    fn(*args, **kw)  # warmup / compile
-    t0 = time.perf_counter()
-    for _ in range(repeat):
-        out = fn(*args, **kw)
-    return (time.perf_counter() - t0) / repeat * 1e6, out

@@ -29,11 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                   # jax >= 0.5
-    _shard_map = jax.shard_map
-except AttributeError:                 # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 FetchFn = Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]
 
 
@@ -77,9 +72,9 @@ def make_pooled_fetch(mesh: Mesh, *, batch_axes=("pod", "data"),
     spec_idx = P(batch, None)
     spec_out = P(batch, None, None)
     body = functools.partial(_pooled_fetch_local, axis=pool_axis)
-    return _shard_map(body, mesh=mesh,
-                      in_specs=(spec_pool, spec_idx),
-                      out_specs=spec_out)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(spec_pool, spec_idx),
+                         out_specs=spec_out)
 
 
 def make_fetch_fn(mesh: Optional[Mesh], backend: str = "local",

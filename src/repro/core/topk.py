@@ -15,13 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                   # jax >= 0.5
-    _shard_map = jax.shard_map
-    _NO_REP_CHECK = {"check_vma": False}
-except AttributeError:                 # older jax: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _NO_REP_CHECK = {"check_rep": False}
-
 from repro.models.dsa import NEG_INF, topk_select  # noqa: F401  (re-export)
 
 
@@ -58,11 +51,11 @@ def make_hierarchical_topk(mesh: Mesh, k: int, *, batch_axes=("pod", "data"),
     import functools
     batch = tuple(a for a in batch_axes if a in mesh.axis_names)
     body = functools.partial(_hier_topk_local, k=k, axis=pool_axis)
-    # replication check off (check_vma / legacy check_rep): the tiled
+    # replication check off (check_vma): the tiled
     # all_gather makes every pool-axis rank's candidate set identical, so
     # the re-top-k output IS replicated over the pool axis — but the
     # inference can't prove it.
-    return _shard_map(body, mesh=mesh,
-                      in_specs=(P(batch, pool_axis), P(batch)),
-                      out_specs=(P(batch, None), P(batch, None)),
-                      **_NO_REP_CHECK)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(batch, pool_axis), P(batch)),
+                         out_specs=(P(batch, None), P(batch, None)),
+                         check_vma=False)

@@ -92,12 +92,8 @@ def _mesh() -> Optional[Mesh]:
     m = getattr(_state, "mesh", None)
     if m is not None:
         return m
-    # fall back to the ambient mesh if one is active (API added in
-    # jax 0.5; older versions have no ambient-mesh concept -> no mesh)
-    get_env = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_env is None:
-        return None
-    env = get_env()
+    # fall back to the ambient mesh if one is active
+    env = jax.sharding.get_abstract_mesh()
     return env if env and env.shape_tuple else None
 
 

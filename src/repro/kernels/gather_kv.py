@@ -7,8 +7,10 @@ the TPU DMA engine streams exactly the requested KV rows HBM->VMEM, one
 descriptor per row, with no intermediate staging.  This is the TPU-native
 form of a fine-grained, memory-semantic gather.
 
-Grid: one step per gathered row.  kv blocks are (1, d) — the row picked by
-``idx[i]``; out blocks are (1, d) at row ``i``.
+Grid: one step per gathered row.  The TPU lowering wants the last two
+block dims to be (8k, 128k) or whole, so rows are viewed as [S, 1, d]:
+kv blocks are (1, d) tiles of the row picked by ``idx[i]`` (leading dim
+squeezed), out blocks the same at row ``i``.
 """
 from __future__ import annotations
 
@@ -26,7 +28,7 @@ def _gather_kernel(idx_ref, kv_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def gather_kv(kv: jnp.ndarray, idx: jnp.ndarray, *, interpret: bool = True
+def gather_kv(kv: jnp.ndarray, idx: jnp.ndarray, *, interpret: bool = False
               ) -> jnp.ndarray:
     """kv: [S, d] (pool shard, HBM); idx: [k] int32 -> [k, d].
 
@@ -34,18 +36,20 @@ def gather_kv(kv: jnp.ndarray, idx: jnp.ndarray, *, interpret: bool = True
     fetch masks them after the gather).
     """
     k = idx.shape[0]
-    d = kv.shape[-1]
-    return pl.pallas_call(
+    S, d = kv.shape
+    row = (pl.Squeezed(), 1, d)
+    out = pl.pallas_call(
         _gather_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(k,),
-            in_specs=[pl.BlockSpec((1, d), lambda i, idx_ref: (idx_ref[i], 0))],
-            out_specs=pl.BlockSpec((1, d), lambda i, idx_ref: (i, 0)),
+            in_specs=[pl.BlockSpec(row, lambda i, idx_ref: (idx_ref[i], 0, 0))],
+            out_specs=pl.BlockSpec(row, lambda i, idx_ref: (i, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((k, d), kv.dtype),
+        out_shape=jax.ShapeDtypeStruct((k, 1, d), kv.dtype),
         interpret=interpret,
-    )(idx, kv)
+    )(idx, kv.reshape(S, 1, d))
+    return out.reshape(k, d)
 
 
 def _gather_block_kernel(idx_ref, kv_ref, out_ref):
@@ -54,7 +58,7 @@ def _gather_block_kernel(idx_ref, kv_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("page", "interpret"))
 def gather_kv_pages(kv: jnp.ndarray, page_idx: jnp.ndarray, *, page: int = 16,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     """Page-granular gather: fetch whole pages of ``page`` consecutive rows.
 
     kv: [S, d] with S % page == 0; page_idx: [n_pages] page numbers

@@ -29,7 +29,7 @@ def _indexer_kernel(keys_ref, q_ref, w_ref, out_ref, *, di: int):
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def indexer_scores(q: jnp.ndarray, w: jnp.ndarray, keys: jnp.ndarray, *,
-                   block_s: int = 512, interpret: bool = True) -> jnp.ndarray:
+                   block_s: int = 512, interpret: bool = False) -> jnp.ndarray:
     """q: [H, di]; w: [H]; keys: [S, di] -> scores [S] f32."""
     S, di = keys.shape
     H = q.shape[0]

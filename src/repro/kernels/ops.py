@@ -1,9 +1,9 @@
 """jit'd batched wrappers over the Pallas kernels (+ ref dispatch).
 
-``use_pallas=False`` (default on this CPU container) routes to the pure-jnp
-oracles in ref.py — the compiled dry-run uses that path, which XLA:TPU
-fuses equivalently; on real TPU hardware flip ``use_pallas=True`` (kernels
-are validated in interpret mode by tests/test_kernels.py).
+``use_pallas=False`` routes to the pure-jnp oracles in ref.py (plain XLA);
+``use_pallas=True`` runs the Pallas kernels, compiled for the TPU.  On the
+CPU the kernels run only with ``interpret=True``, which is how
+tests/test_kernels.py checks them against the oracles.
 """
 from __future__ import annotations
 
@@ -22,7 +22,7 @@ from repro.kernels.sparse_attn import NEG_INF, sparse_attn
 
 
 def batched_gather(kv: jnp.ndarray, idx: jnp.ndarray, *,
-                   use_pallas: bool = False, interpret: bool = True
+                   use_pallas: bool = False, interpret: bool = False
                    ) -> jnp.ndarray:
     """kv: [B, S, d]; idx: [B, k] -> [B, k, d]."""
     if use_pallas:
@@ -33,7 +33,7 @@ def batched_gather(kv: jnp.ndarray, idx: jnp.ndarray, *,
 
 def batched_indexer_scores(q: jnp.ndarray, w: jnp.ndarray, keys: jnp.ndarray,
                            *, use_pallas: bool = False,
-                           interpret: bool = True) -> jnp.ndarray:
+                           interpret: bool = False) -> jnp.ndarray:
     """q: [B, H, di]; w: [B, H]; keys: [B, S, di] -> [B, S] f32."""
     if use_pallas:
         return jax.vmap(lambda a, b, c: indexer_scores_pl(
@@ -44,7 +44,7 @@ def batched_indexer_scores(q: jnp.ndarray, w: jnp.ndarray, keys: jnp.ndarray,
 def batched_sparse_mla(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
                        entries: jnp.ndarray, valid: jnp.ndarray, *,
                        dc: int, scale: float, use_pallas: bool = False,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool = False) -> jnp.ndarray:
     """q_lat: [B,H,dc]; q_pe: [B,H,dr]; entries: [B,k,dc+dr]; valid: [B,k]
     -> out_lat [B,H,dc] f32."""
     if use_pallas:
@@ -60,7 +60,7 @@ def batched_sparse_mla(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
 
 def batched_sparse_gqa(q: jnp.ndarray, entries: jnp.ndarray,
                        valid: jnp.ndarray, *, n_kv: int,
-                       use_pallas: bool = False, interpret: bool = True
+                       use_pallas: bool = False, interpret: bool = False
                        ) -> jnp.ndarray:
     """q: [B,H,hd]; entries: [B,k,2*n_kv*hd]; valid: [B,k] -> [B,H,hd]."""
     B, H, hd = q.shape
@@ -86,7 +86,7 @@ def batched_sparse_gqa(q: jnp.ndarray, entries: jnp.ndarray,
 
 def batched_scatter(pool: jnp.ndarray, entries: jnp.ndarray,
                     idx: jnp.ndarray, *, use_pallas: bool = False,
-                    interpret: bool = True) -> jnp.ndarray:
+                    interpret: bool = False) -> jnp.ndarray:
     """pool: [B,S,d]; entries: [B,k,d]; idx: [B,k] -> updated pool."""
     if use_pallas:
         return jax.vmap(lambda p, e, i: scatter_kv(p, e, i,

@@ -62,7 +62,7 @@ def _sparse_attn_kernel(q_ref, k_ref, v_ref, bias_ref, out_ref,
                    static_argnames=("scale", "block_k", "interpret"))
 def sparse_attn(q: jnp.ndarray, keys: jnp.ndarray, vals: jnp.ndarray,
                 bias: jnp.ndarray, *, scale: float, block_k: int = 256,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool = False) -> jnp.ndarray:
     """q: [H, dq]; keys: [k, dq]; vals: [k, dv]; bias: [k] f32 (0 / -inf)
     -> out [H, dv] f32."""
     H, dq = q.shape
@@ -84,8 +84,6 @@ def sparse_attn(q: jnp.ndarray, keys: jnp.ndarray, vals: jnp.ndarray,
         out_specs=pl.BlockSpec((H, dv), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((H, dv), jnp.float32),
         scratch_shapes=[
-            # pltpu.VMEM is the canonical scratch constructor and exists
-            # across jax versions (MemorySpace.VMEM is 0.5+-only)
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, 1), jnp.float32),
             pltpu.VMEM((H, dv), jnp.float32),

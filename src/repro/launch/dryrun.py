@@ -1,4 +1,7 @@
 import os
+# CPU only, for itself and every child of the --all sweep: this process
+# imports JAX before it starts them, so on a TPU host it would hold the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the

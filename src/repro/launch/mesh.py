@@ -6,18 +6,11 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
-
-try:                                   # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:                    # older jax: meshes are Auto-only
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,16 +1,39 @@
-"""End-to-end serving driver: the real engine on a reduced config (CPU)
-or the full config under the production mesh (real hardware).
+"""End-to-end serving entry point: the real engine on one device.
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b \
         --reduced --requests 8 --ctx 48 --out-len 8 --backend cxl
+
+``--reduced`` shrinks the config to a tiny one of the same family, for the
+CPU.  Without it the config runs at its published width on the default
+device; ``python chip_smoke.py`` serves qwen2-1.5b that way on one TPU.
+The ``ttft_*`` and ``tbt_*`` latencies in the output come from the
+engine's modelled clock, not from the device.
 """
 from __future__ import annotations
 
 import argparse
 import json
+import os
+from pathlib import Path
+
+REPO = Path(__file__).resolve().parents[3]
 
 
-def main():
+def use_compile_cache():
+    """Keep compiled programs across processes: in
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set (JAX reads it itself),
+    else in ``<repo>/.jax_cache`` — a fixed path, since the path is part
+    of the cache key.  Call before the first compile."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO / ".jax_cache"))
+    # JAX skips programs that compile in under a second by default; a
+    # serve start compiles dozens of those (parameter-init pieces, scatters)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--reduced", action="store_true")
@@ -198,7 +221,13 @@ def main():
                     help="per-request mean TBT SLO target in seconds "
                          "(reported as slo_tbt_attainment; 0 = off)")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def run(argv=None):
+    """Build the engine and the request trace from ``argv`` and serve the
+    trace.  Returns ``(output dict, engine, requests)``."""
+    args = parse_args(argv)
 
     import dataclasses
 
@@ -299,8 +328,16 @@ def main():
                               vocab=cfg.vocab)
     out = eng.run(reqs, slo_ttft_s=args.slo_ttft, slo_tbt_s=args.slo_tbt)
     out["buffer_hit_rate"] = eng.stats.hit_rate
+    return out, eng, reqs
+
+
+def main(argv=None) -> dict:
+    """Command-line entry: serve, print the output dict, return it."""
+    use_compile_cache()
+    out, _, _ = run(argv)
     print(json.dumps({k: (round(v, 5) if isinstance(v, float) else v)
                       for k, v in out.items()}, indent=1))
+    return out
 
 
 if __name__ == "__main__":

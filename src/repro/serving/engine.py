@@ -1,6 +1,7 @@
 """Decode engine: continuous batching over the SAC cache — the *real*
 JAX serving path (compiled prefill/decode steps + host-side SACSystem
-bookkeeping), runnable end-to-end on CPU with reduced configs.
+bookkeeping).  It runs on one TPU at a config's published width
+(chip_smoke.py) and on the CPU with reduced configs.
 
 This is the functional counterpart of the simulator: the simulator
 answers "what would the cluster do", the engine actually *does* it for
@@ -493,8 +494,7 @@ class Engine:
                     max_slots=self.buffer_width)
 
         self._decode = jax.jit(self.model.decode)
-        self._prefill_one = jax.jit(
-            lambda p, toks: self.model.prefill(p, toks))
+        self._prefill_one = jax.jit(self.model.prefill)
         self._warm = jax.jit(self._warm_apply)
         self.state = self.model.init_serve_state(
             slots, max_ctx,

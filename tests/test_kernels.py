@@ -1,5 +1,6 @@
 """Pallas kernel validation: shape/dtype sweeps, allclose vs ref.py
-oracles (interpret=True executes the kernel body on CPU)."""
+oracles.  Every call passes interpret=True, which executes the kernel body
+on the CPU; the kernels default to compiling for the TPU."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,7 +21,7 @@ KEY = jax.random.PRNGKey(42)
 def test_gather_kv_sweep(S, d, k, dtype):
     kv = jax.random.normal(KEY, (S, d), dtype)
     idx = jax.random.randint(KEY, (k,), 0, S)
-    out = gather_kv(kv, idx)
+    out = gather_kv(kv, idx, interpret=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref.gather_kv_ref(kv, idx),
                                           np.float32))
@@ -31,7 +32,7 @@ def test_gather_pages(page):
     S, d, n = 128, 64, 4
     kv = jax.random.normal(KEY, (S, d), jnp.bfloat16)
     pidx = jnp.array([0, 3, 5, 7], jnp.int32)
-    out = gather_kv_pages(kv, pidx, page=page)
+    out = gather_kv_pages(kv, pidx, page=page, interpret=True)
     expect = kv.reshape(S // page, page, d)[pidx].reshape(n * page, d)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32))
@@ -43,7 +44,7 @@ def test_indexer_sweep(S, di, H):
     q = jax.random.normal(KEY, (H, di), jnp.bfloat16)
     w = jax.random.normal(KEY, (H,), jnp.bfloat16)
     keys = jax.random.normal(KEY, (S, di), jnp.bfloat16)
-    out = indexer_scores(q, w, keys, block_s=256)
+    out = indexer_scores(q, w, keys, block_s=256, interpret=True)
     expect = ref.indexer_scores_ref(q, w, keys)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-2, atol=2e-2)
@@ -59,7 +60,8 @@ def test_sparse_attn_sweep(k, H, dq, dv, block):
     valid = jax.random.bernoulli(KEY, 0.8, (k,)).at[0].set(True)
     bias = jnp.where(valid, 0.0, -1e30).astype(jnp.float32)
     scale = 1.0 / np.sqrt(dq)
-    out = sparse_attn(q, keys, vals, bias, scale=scale, block_k=block)
+    out = sparse_attn(q, keys, vals, bias, scale=scale, block_k=block,
+                      interpret=True)
     # oracle: dense softmax attention over valid entries
     s = (q.astype(jnp.float32) @ keys.astype(jnp.float32).T) * scale
     s = jnp.where(valid[None, :], s, -1e30)
@@ -74,7 +76,7 @@ def test_scatter_inplace_semantics():
     pool = jax.random.normal(KEY, (S, d), jnp.bfloat16)
     entries = jax.random.normal(jax.random.PRNGKey(7), (k, d), jnp.bfloat16)
     idx = jnp.array([1, 5, 9, 13, 17, 21, 25, 29], jnp.int32)
-    out = scatter_kv(pool, entries, idx)
+    out = scatter_kv(pool, entries, idx, interpret=True)
     expect = ref.scatter_kv_ref(pool, entries, idx)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32))
@@ -89,7 +91,7 @@ def test_ops_mla_equivalence():
     entries = jax.random.normal(KEY, (B, k, dc + dr), jnp.bfloat16)
     valid = jax.random.bernoulli(KEY, 0.7, (B, k)).at[:, 0].set(True)
     a = ops.batched_sparse_mla(q_lat, q_pe, entries, valid, dc=dc,
-                               scale=0.11, use_pallas=True)
+                               scale=0.11, use_pallas=True, interpret=True)
     b = ops.batched_sparse_mla(q_lat, q_pe, entries, valid, dc=dc,
                                scale=0.11, use_pallas=False)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -101,7 +103,8 @@ def test_ops_gqa_equivalence():
     q = jax.random.normal(KEY, (B, H, hd), jnp.bfloat16)
     entries = jax.random.normal(KEY, (B, k, 2 * n_kv * hd), jnp.bfloat16)
     valid = jnp.ones((B, k), bool)
-    a = ops.batched_sparse_gqa(q, entries, valid, n_kv=n_kv, use_pallas=True)
+    a = ops.batched_sparse_gqa(q, entries, valid, n_kv=n_kv, use_pallas=True,
+                               interpret=True)
     b = ops.batched_sparse_gqa(q, entries, valid, n_kv=n_kv, use_pallas=False)
     np.testing.assert_allclose(np.asarray(a, np.float32),
                                np.asarray(b, np.float32), rtol=3e-2, atol=3e-2)
