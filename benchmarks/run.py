@@ -1,6 +1,5 @@
-"""Benchmark harness: one module per paper table/figure + the roofline
-aggregation.  The figures are simulator outputs for the paper's GPU+CXL
-testbed, not chip measurements.
+"""Benchmark harness: one module per paper table/figure.  The figures are
+simulator outputs for the paper's GPU+CXL testbed, not chip measurements.
 
     PYTHONPATH=src python -m benchmarks.run [--quick] [--only fig10,fig13]
 
@@ -18,13 +17,12 @@ def main() -> None:
                     help="reduced traces (CI-speed)")
     ap.add_argument("--only", default="",
                     help="comma list: fig5,fig9,fig10,fig11,fig12,fig13,"
-                         "fig14,prefetch,roofline")
+                         "fig14,prefetch")
     args = ap.parse_args()
 
     from benchmarks import (appendix_d, fig5_retrieval, fig9_round1,
                             fig10_round2, fig11_scalability, fig12_nondisagg,
-                            fig13_interleave, fig14_buffer, prefetch_sweep,
-                            roofline)
+                            fig13_interleave, fig14_buffer, prefetch_sweep)
     from benchmarks.common import Csv
 
     mods = {
@@ -32,7 +30,7 @@ def main() -> None:
         "fig11": fig11_scalability, "fig12": fig12_nondisagg,
         "fig13": fig13_interleave, "fig14": fig14_buffer,
         "prefetch": prefetch_sweep,
-        "appendixD": appendix_d, "roofline": roofline,
+        "appendixD": appendix_d,
     }
     only = [s.strip() for s in args.only.split(",") if s.strip()]
     csv = Csv()

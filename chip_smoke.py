@@ -12,16 +12,15 @@ Every phase runs in this one process, since a chip belongs to one process:
    on; the prompt exceeds both the top-k (2048) and the hot tier (6144
    entries), and the 8 requests refill freed slots.  Token counts, token
    range and hot-tier counters are checked;
-3. decode timing: the engine's jitted decode step on its final state, after
-   warm-up, each step ended by ``block_until_ready``;
-4. witness: the same parameters, initialized on the CPU and copied to the
+3. witness: the same parameters, initialized on the CPU and copied to the
    chip, run one prefill of a short prompt and a few decode steps on each
    backend, and the logits of every step must agree to ``LOGIT_RTOL``.
 
 The lines before the last report, per phase, the backend compile seconds of
 each jitted function (its first call; a persistent-cache hit compiles
 nothing and is counted in ``persistent_cache_hits``), wall times on the
-host clock, peak device memory and the engine's counts.  The engine's
+host clock and the engine's counts (``chipbench/`` measures the decode
+step inside the serving loop).  The engine's
 modelled latencies are not printed: they are not measurements.  No phase catches an exception: any failure exits non-zero
 before the last line, which is ``{"ok": true, "device": {...}}`` only for the
 full run on a TPU.
@@ -43,7 +42,6 @@ if os.environ.get("JAX_PLATFORMS") and \
     os.environ["JAX_PLATFORMS"] += ",cpu"
 
 import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
@@ -60,7 +58,6 @@ from repro.models.model import build_model  # noqa: E402
 # relative L2 error of each step's logits vector.
 LOGIT_RTOL = 5e-2
 WITNESS_STEPS = 4
-TIMED_STEPS = 8
 REQUESTS, OUT_LEN = 8, 64
 SIZES = {
     # (serve.py arguments, witness prompt tokens)
@@ -183,20 +180,6 @@ def engine_run(argv: list, vocab: int):
     return eng, counts, wall
 
 
-def time_decode(eng) -> list:
-    """Wall seconds of the engine's decode step at its batch, warm."""
-    tokens = jnp.zeros((eng.slots,), jnp.int32)
-    state, logits = eng._decode(eng.params, eng.state, tokens)
-    jax.block_until_ready(logits)
-    times = []
-    for _ in range(TIMED_STEPS):
-        t0 = time.perf_counter()
-        state, logits = eng._decode(eng.params, state, tokens)
-        jax.block_until_ready((state, logits))
-        times.append(time.perf_counter() - t0)
-    return times
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--reduced", action="store_true",
@@ -226,11 +209,6 @@ def main(argv=None):
     eng, counts, wall = engine_run(serve_argv, cfg.vocab)
     emit("engine", argv=serve_argv, wall_s=wall, **counts, **log.take())
 
-    times = time_decode(eng)
-    stats = dev.memory_stats() or {}
-    emit("decode_timing", batch=eng.slots, max_ctx=eng.max_ctx,
-         step_wall_s=times, step_wall_median_s=float(np.median(times)),
-         peak_bytes_in_use=stats.get("peak_bytes_in_use"), **log.take())
     del eng
 
     witness(cfg, prompt_len, dev, args.seed, log)

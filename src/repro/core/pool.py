@@ -111,11 +111,12 @@ def pool_write(pool: jnp.ndarray, new_entries: jnp.ndarray,
     and aliases the donated pool buffer.
     """
     S = pool.shape[2]
-    pos_c = jnp.clip(pos, 0, S - 1)
-    mask = (jnp.arange(S, dtype=jnp.int32)[None, :]
-            == pos_c[:, None])                       # [B, S]
-    return jnp.where(mask[None, :, :, None],
-                     new_entries.astype(pool.dtype)[:, :, None, :], pool)
+    with jax.named_scope("pool_write"):
+        pos_c = jnp.clip(pos, 0, S - 1)
+        mask = (jnp.arange(S, dtype=jnp.int32)[None, :]
+                == pos_c[:, None])                       # [B, S]
+        return jnp.where(mask[None, :, :, None],
+                         new_entries.astype(pool.dtype)[:, :, None, :], pool)
 
 
 def pool_write_prefill(pool: jnp.ndarray, entries: jnp.ndarray,

@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
@@ -79,54 +80,64 @@ def sparse_attend(p_attn: Dict, p_idx: Dict, x: jnp.ndarray, cfg: ModelConfig,
     inside the returned buffer state measure inserted/useful speculation
     for the host's wasted-traffic accounting (serving/prefetch.py).
     """
-    scores = dsa.indexer_scores(p_idx, x, idx_pool_l, cfg)
-    if window:
-        # candidate set = (cache_len - window, cache_len]: size-`window`
-        # trailing window including the (appended) current token.
-        pos = jnp.arange(scores.shape[-1], dtype=jnp.int32)
-        in_win = pos[None, :] > (cache_len[:, None] - window)
-        scores = jnp.where(in_win, scores, dsa.NEG_INF)
+    # the named scopes label the decode program's ops by part, for the
+    # device trace; they change no op
+    with jax.named_scope("indexer"):
+        scores = dsa.indexer_scores(p_idx, x, idx_pool_l, cfg)
+        if window:
+            # candidate set = (cache_len - window, cache_len]: size-`window`
+            # trailing window including the (appended) current token.
+            pos = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+            in_win = pos[None, :] > (cache_len[:, None] - window)
+            scores = jnp.where(in_win, scores, dsa.NEG_INF)
     speculate = buf_state is not None and prefetch_width > 0
     p_idx_ = p_valid = None
-    if topk_fn is not None:
-        idx, valid = topk_fn(scores, cache_len)
-    elif speculate and prefetch_fn is None:
-        # fused selection: one top_k(k+w) yields the (bit-identical)
-        # demand set AND the speculation tail
-        idx, valid, p_idx_, p_valid = dsa.topk_select_with_tail(
-            scores, cache_len, cfg.sac.topk, prefetch_width, score_margin)
-    else:
-        idx, valid = dsa.topk_select(scores, cache_len, cfg.sac.topk)
-    fetched = fetch_fn(kv_pool_l, idx)
+    with jax.named_scope("topk"):
+        if topk_fn is not None:
+            idx, valid = topk_fn(scores, cache_len)
+        elif speculate and prefetch_fn is None:
+            # fused selection: one top_k(k+w) yields the (bit-identical)
+            # demand set AND the speculation tail
+            idx, valid, p_idx_, p_valid = dsa.topk_select_with_tail(
+                scores, cache_len, cfg.sac.topk, prefetch_width,
+                score_margin)
+        else:
+            idx, valid = dsa.topk_select(scores, cache_len, cfg.sac.topk)
+    with jax.named_scope("gather"):
+        fetched = fetch_fn(kv_pool_l, idx)
     if buf_state is not None:
-        fetched, buf_state, hits, misses = hisparse.read_through(
-            buf_state, idx, fetched, valid)
-        if speculate:
-            if p_idx_ is None:
-                p_idx_, p_valid = (
-                    prefetch_fn(scores, cache_len) if prefetch_fn is not None
-                    else dsa.speculate_next_topk(scores, cache_len,
-                                                 cfg.sac.topk,
-                                                 prefetch_width,
-                                                 score_margin))
-            if pf_budget is not None:
-                # arbiter-granted cap: only the first budget[b] lanes may
-                # issue (lanes are best-first) — traffic shaping only
-                p_valid = dsa.budget_mask(p_valid, pf_budget)
-            p_vals = fetch_fn(kv_pool_l, jnp.clip(
-                p_idx_, 0, kv_pool_l.shape[1] - 1))
-            buf_state, _ = hisparse.warm_insert(buf_state, p_idx_, p_vals,
-                                                p_valid)
-    fetched = jnp.concatenate(
-        [fetched, own_entry[:, None, :].astype(fetched.dtype)], axis=1)
-    valid = jnp.concatenate(
-        [valid, jnp.ones((valid.shape[0], 1), bool)], axis=1)
-    if cfg.mla:
-        out = dsa.mla_absorbed_decode(p_attn, x, cfg, fetched, valid,
-                                      positions)
-    else:
-        out = dsa.gqa_sparse_decode(p_attn, x, cfg, fetched, valid,
-                                    positions)
+        with jax.named_scope("hot_tier"):
+            fetched, buf_state, hits, misses = hisparse.read_through(
+                buf_state, idx, fetched, valid)
+            if speculate:
+                if p_idx_ is None:
+                    p_idx_, p_valid = (
+                        prefetch_fn(scores, cache_len)
+                        if prefetch_fn is not None
+                        else dsa.speculate_next_topk(scores, cache_len,
+                                                     cfg.sac.topk,
+                                                     prefetch_width,
+                                                     score_margin))
+                if pf_budget is not None:
+                    # arbiter-granted cap: only the first budget[b] lanes
+                    # may issue (lanes are best-first) — traffic shaping
+                    # only
+                    p_valid = dsa.budget_mask(p_valid, pf_budget)
+                p_vals = fetch_fn(kv_pool_l, jnp.clip(
+                    p_idx_, 0, kv_pool_l.shape[1] - 1))
+                buf_state, _ = hisparse.warm_insert(buf_state, p_idx_,
+                                                    p_vals, p_valid)
+    with jax.named_scope("attention"):
+        fetched = jnp.concatenate(
+            [fetched, own_entry[:, None, :].astype(fetched.dtype)], axis=1)
+        valid = jnp.concatenate(
+            [valid, jnp.ones((valid.shape[0], 1), bool)], axis=1)
+        if cfg.mla:
+            out = dsa.mla_absorbed_decode(p_attn, x, cfg, fetched, valid,
+                                          positions)
+        else:
+            out = dsa.gqa_sparse_decode(p_attn, x, cfg, fetched, valid,
+                                        positions)
     if buf_state is not None:
         return out, buf_state, hits, misses
     return out

@@ -204,14 +204,15 @@ def _mlp_apply(p_mlp, x, cfg, *, decode: bool = False):
     decode steps route a handful of tokens — grouping them fragments the
     expert batches and regresses the collective term (§Perf B-series).
     """
-    if cfg.n_experts:
-        groups = 1 if decode else _opt("moe_groups", 1)
-        out, aux = moe.moe_block(p_mlp, x, cfg, groups=groups)
-        return out, aux
-    h = constrain(x @ p_mlp["w_gate"], ("B", "Sq", "F"))
-    h = jax.nn.silu(h) * (x @ p_mlp["w_up"])
-    out = h @ p_mlp["w_down"]
-    return out, jnp.float32(0)
+    with jax.named_scope("mlp"):
+        if cfg.n_experts:
+            groups = 1 if decode else _opt("moe_groups", 1)
+            out, aux = moe.moe_block(p_mlp, x, cfg, groups=groups)
+            return out, aux
+        h = constrain(x @ p_mlp["w_gate"], ("B", "Sq", "F"))
+        h = jax.nn.silu(h) * (x @ p_mlp["w_up"])
+        out = h @ p_mlp["w_down"]
+        return out, jnp.float32(0)
 
 
 def _attn_fwd(p, x, cfg, positions, window):
@@ -343,27 +344,30 @@ def _attn_decode(p, x, cfg, ctx, kv_slice, idx_slice, window, hbuf=None):
     hot-tier state (core/hisparse.py) or None; the last three outputs are
     None unless a buffer was threaded in.
     """
-    xn = rms_norm(x, p["ln1"])
     positions, cache_len = ctx["positions"], ctx["cache_len"]
-    if cfg.mla:
-        own = dsa.mla_kv_entry(p["attn"], xn, cfg, positions)
-    else:
-        own = dsa.gqa_kv_entry(p["attn"], xn, cfg, positions)
-    if ctx["mode"] == "dense" or not cfg.sac.enabled:
-        if window:
-            delta = sac_core.window_attend(
-                p["attn"], xn, cfg, kv_slice, cache_len, positions, own,
-                window, fetch_fn=ctx["fetch_fn"])
+    with jax.named_scope("attention"):
+        xn = rms_norm(x, p["ln1"])
+        if cfg.mla:
+            own = dsa.mla_kv_entry(p["attn"], xn, cfg, positions)
         else:
-            delta = sac_core.dense_attend(p["attn"], xn, cfg, kv_slice,
-                                          cache_len, positions, own)
+            own = dsa.gqa_kv_entry(p["attn"], xn, cfg, positions)
+    if ctx["mode"] == "dense" or not cfg.sac.enabled:
+        with jax.named_scope("attention"):
+            if window:
+                delta = sac_core.window_attend(
+                    p["attn"], xn, cfg, kv_slice, cache_len, positions, own,
+                    window, fetch_fn=ctx["fetch_fn"])
+            else:
+                delta = sac_core.dense_attend(p["attn"], xn, cfg, kv_slice,
+                                              cache_len, positions, own)
         new_key = jnp.zeros((x.shape[0], cfg.sac.d_idx), DTYPE)
         if hbuf is not None:   # keep scan pytree structure: untouched buffer
             zero = jnp.zeros((x.shape[0],), jnp.int32)
             return delta, own, new_key, hbuf, zero, zero
         return delta, own, new_key, None, None, None
     # SAC path: indexer -> top-k -> fetch -> sparse attention
-    new_key = dsa.indexer_keys(p["idx"], xn)
+    with jax.named_scope("indexer"):
+        new_key = dsa.indexer_keys(p["idx"], xn)
     if hbuf is None:
         delta = sac_core.sparse_attend(
             p["attn"], p["idx"], xn, cfg, kv_slice, idx_slice, cache_len,
@@ -690,8 +694,11 @@ class TransformerLM:
         hot = state.get("hot_buf")    # layered hisparse.BufferState or None
         # speculative-prefetch step deltas: the pf_* counters inside the
         # buffer are cumulative, so the step's contribution is post - pre
-        pf_ins0 = hot.pf_inserted.sum(0) if hot is not None else None
-        pf_use0 = hot.pf_used.sum(0) if hot is not None else None
+        # The named scopes here and in the layer bodies label the ops by
+        # part for the device trace; they change no op.
+        with jax.named_scope("hot_tier"):
+            pf_ins0 = hot.pf_inserted.sum(0) if hot is not None else None
+            pf_use0 = hot.pf_used.sum(0) if hot is not None else None
         pool_closure = bool(self.opts.get("pool_closure"))
         use_idx = idx_pool is not None and self.mode == "sac"
         new_entries, new_keys = [], []
@@ -707,10 +714,11 @@ class TransformerLM:
                 # [n, a, ...] so the scan threads one [a, ...] slice per
                 # iteration (mutable xs/ys — unlike the read-only pools,
                 # the buffer is UPDATED by every layer's read_through)
-                hb_g = jax.tree.map(
-                    lambda t: jax.lax.dynamic_slice_in_dim(
-                        t, kv_off, seg.n * a, 0).reshape(
-                            seg.n, a, *t.shape[1:]), hot)
+                with jax.named_scope("hot_tier"):
+                    hb_g = jax.tree.map(
+                        lambda t: jax.lax.dynamic_slice_in_dim(
+                            t, kv_off, seg.n * a, 0).reshape(
+                                seg.n, a, *t.shape[1:]), hot)
 
             if pool_closure and a and kv_pool is not None:
                 # §Perf C4: pools stay closure-captured, FLAT — each
@@ -720,10 +728,12 @@ class TransformerLM:
                 # pool) and no scan-xs streaming (which double-buffers it).
                 def scan_body(x, xs, _body=body, _off=kv_off, _a=a):
                     p, i, hb, rc = xs
-                    kv = jax.lax.dynamic_slice_in_dim(
-                        kv_pool, _off + i * _a, _a, 0)
-                    ik = jax.lax.dynamic_slice_in_dim(
-                        idx_pool, _off + i * _a, _a, 0) if use_idx else None
+                    with jax.named_scope("pool_slice"):
+                        kv = jax.lax.dynamic_slice_in_dim(
+                            kv_pool, _off + i * _a, _a, 0)
+                        ik = (jax.lax.dynamic_slice_in_dim(
+                            idx_pool, _off + i * _a, _a, 0) if use_idx
+                            else None)
                     x, own, keys, hb2, hm, rc2 = _body(x, p, kv, ik, hb,
                                                        rc, ctx)
                     return x, (own, keys, hb2, hm, rc2)
@@ -734,14 +744,15 @@ class TransformerLM:
             else:
                 if a and kv_pool is not None:
                     S = kv_pool.shape[2]
-                    kv_g = jax.lax.dynamic_slice_in_dim(
-                        kv_pool, kv_off, seg.n * a, 0).reshape(
-                            seg.n, a, B, S, kv_pool.shape[-1])
-                    ik_g = None
-                    if use_idx:
-                        ik_g = jax.lax.dynamic_slice_in_dim(
-                            idx_pool, kv_off, seg.n * a, 0).reshape(
-                                seg.n, a, B, S, idx_pool.shape[-1])
+                    with jax.named_scope("pool_slice"):
+                        kv_g = jax.lax.dynamic_slice_in_dim(
+                            kv_pool, kv_off, seg.n * a, 0).reshape(
+                                seg.n, a, B, S, kv_pool.shape[-1])
+                        ik_g = None
+                        if use_idx:
+                            ik_g = jax.lax.dynamic_slice_in_dim(
+                                idx_pool, kv_off, seg.n * a, 0).reshape(
+                                    seg.n, a, B, S, idx_pool.shape[-1])
                     seg_off, kv_off = kv_off, kv_off + seg.n * a
                 else:
                     kv_g, ik_g, seg_off = None, None, kv_off
@@ -753,25 +764,32 @@ class TransformerLM:
                     return x, (own, keys, hb2, hm, rc2)
 
                 xs = (params["segments"][si], kv_g, ik_g, hb_g, rec)
-            x, (own, keys, hb2, hm, rec2) = jax.lax.scan(scan_body, x, xs)
+            # "layers": what the scan itself does (each layer's slice of
+            # the stacked weights, pools and hot tier; stacking its outputs)
+            with jax.named_scope("layers"):
+                x, (own, keys, hb2, hm, rec2) = jax.lax.scan(scan_body, x,
+                                                             xs)
             if own is not None:
                 new_entries.append(own.reshape(-1, B, own.shape[-1]))
                 new_keys.append(keys.reshape(-1, B, keys.shape[-1]))
             if hb2 is not None:
                 # fold the segment's updated [n, a, ...] buffer block back
                 # into the layered [L, ...] state
-                flat = jax.tree.map(
-                    lambda t: t.reshape(t.shape[0] * t.shape[1],
-                                        *t.shape[2:]), hb2)
-                hot = jax.tree.map(
-                    lambda full, upd, _o=seg_off:
-                        jax.lax.dynamic_update_slice_in_dim(full, upd, _o, 0),
-                    hot, flat)
+                with jax.named_scope("hot_tier"):
+                    flat = jax.tree.map(
+                        lambda t: t.reshape(t.shape[0] * t.shape[1],
+                                            *t.shape[2:]), hb2)
+                    hot = jax.tree.map(
+                        lambda full, upd, _o=seg_off:
+                            jax.lax.dynamic_update_slice_in_dim(
+                                full, upd, _o, 0),
+                        hot, flat)
             if hm is not None:
                 # hm: ([n, a, B], [n, a, B]) — flatten to this segment's
                 # kv layers in pool order
-                hits_l.append(hm[0].reshape(-1, B))
-                misses_l.append(hm[1].reshape(-1, B))
+                with jax.named_scope("hot_tier"):
+                    hits_l.append(hm[0].reshape(-1, B))
+                    misses_l.append(hm[1].reshape(-1, B))
             if rec2 is not None:
                 state = dict(state)
                 state[f"rec_{si}"] = rec2
@@ -787,19 +805,21 @@ class TransformerLM:
             # per-step measured hot-tier outcomes, per layer ([L, B]) and
             # summed; the engine charges miss-only fabric traffic from the
             # totals and feeds the per-layer miss rates to the LayerSizer
-            hl = (jnp.concatenate(hits_l, 0) if hits_l
-                  else jnp.zeros((self.n_kv, B), jnp.int32))
-            ml = (jnp.concatenate(misses_l, 0) if misses_l
-                  else jnp.zeros((self.n_kv, B), jnp.int32))
-            state["buf_hits_l"] = hl
-            state["buf_misses_l"] = ml
-            state["buf_hits"] = hl.sum(0)
-            state["buf_misses"] = ml.sum(0)
-            state["pf_inserted"] = hot.pf_inserted.sum(0) - pf_ins0
-            state["pf_useful"] = hot.pf_used.sum(0) - pf_use0
+            with jax.named_scope("hot_tier"):
+                hl = (jnp.concatenate(hits_l, 0) if hits_l
+                      else jnp.zeros((self.n_kv, B), jnp.int32))
+                ml = (jnp.concatenate(misses_l, 0) if misses_l
+                      else jnp.zeros((self.n_kv, B), jnp.int32))
+                state["buf_hits_l"] = hl
+                state["buf_misses_l"] = ml
+                state["buf_hits"] = hl.sum(0)
+                state["buf_misses"] = ml.sum(0)
+                state["pf_inserted"] = hot.pf_inserted.sum(0) - pf_ins0
+                state["pf_useful"] = hot.pf_used.sum(0) - pf_use0
         state["cache_len"] = cache_len + 1
-        x = rms_norm(x, params["final_norm"])
-        logits = (x @ params["lm_head"]).astype(jnp.float32)
+        with jax.named_scope("lm_head"):
+            x = rms_norm(x, params["final_norm"])
+            logits = (x @ params["lm_head"]).astype(jnp.float32)
         return state, constrain(logits, ("B", "V"))
 
     # -- state builders ---------------------------------------------------------

@@ -63,6 +63,7 @@ from repro.serving.prefetch import FetchPlanner, cap_warmup
 from repro.serving.radix import RadixIndex
 from repro.serving.request import Request, summarize
 from repro.serving.simulator import profile_from_config
+from repro.serving.spans import Span, SpanLog
 
 
 @dataclasses.dataclass
@@ -94,6 +95,8 @@ class EngineStats:
     shed_requests: int = 0          # requests dropped by EDF load
                                     # shedding before admission (PR 10
                                     # SLO-aware admission policy)
+    device_reads: int = 0           # device values the engine waited for
+                                    # and copied to the host
     traffic: TrafficStats = dataclasses.field(default_factory=TrafficStats)
     # measured per-layer hot-tier outcomes ([L] arrays, accumulated per
     # step) — the LayerSizer's miss-rate signal (serving/arbiter.py)
@@ -493,6 +496,9 @@ class Engine:
                     n_kv, total, layer_windows=wins, topk=cfg.sac.topk,
                     max_slots=self.buffer_width)
 
+        # the host phases of each step (serving/spans.py): profiler
+        # annotations, and a ring of the last steps that phase_log() reads
+        self.spans = SpanLog()
         self._decode = jax.jit(self.model.decode)
         self._prefill_one = jax.jit(self.model.prefill)
         self._warm = jax.jit(self._warm_apply)
@@ -514,6 +520,16 @@ class Engine:
         # when no layer moved more than cfg.sac.resize_epsilon since,
         # the sizer run (and its sentinel churn) is skipped
         self._resize_rates_ref: Optional[List[float]] = None
+
+    def phase_log(self) -> List[List[Span]]:
+        """The host spans of the last ``spans.STEPS_KEPT`` steps, oldest
+        first; each step's list starts with its ``Engine.step`` span."""
+        return self.spans.steps()
+
+    def _read(self, x) -> np.ndarray:
+        """A device value on the host: waits for it and copies it."""
+        self.stats.device_reads += 1
+        return np.asarray(x)
 
     @property
     def _last_demand_s(self) -> List[float]:
@@ -677,71 +693,72 @@ class Engine:
         each mode (monolithic / chunked / disagg lane) pays those on its
         own schedule.  Returns None when the pool is exhausted (pins
         released; the caller requeues at the head)."""
-        prompt = req.prompt_tokens[: req.context_len]
-        toks = prompt.tolist()
-        # radix prefix lookup — PAGE-granular reuse (crediting the
-        # raw token walk would count prefix tokens no cached page
-        # backs).  The BACKING node's path is pinned immediately so
-        # the pool-pressure eviction inside place() cannot free the
-        # pages we are about to reuse.
-        m = self.radix.match(toks) if self.radix is not None else None
-        pins: List[list] = []
-        if m is not None and m.hit:
-            pins.append(list(m.pin_tokens))
-            self.radix.pin(pins[-1])
-            if self.replicate_on:
-                # the pin above keeps the node alive through the
-                # copy; a successful replication re-matches so the
-                # placer sees every copy (same node, same pin path)
-                m2 = self._maybe_replicate(m, toks, len(prompt))
-                if m2 is not None and m2.hit:
-                    m = m2
-        bonus_s = (self._locality_bonus_s(len(prompt), m.paged_tokens)
-                   if pins else 0.0)
-        rp = self.sac.place(req.request_id, len(prompt) + req.output_len,
-                            affinity=sorted(m.copies) if pins else None,
-                            affinity_s=bonus_s)
-        if rp is None:
-            for p in pins:
-                self.radix.release(p)
-            return None
-        req.dispatch_s = self.clock_s
-        req.pool_device = rp.device
-        # reuse is only real on a device holding a copy of the
-        # cached pages (off-device, the prefix would cross two
-        # fabric links — no better than recomputing); radix_affinity
-        # placement + replication are what make this coincide
-        matched = (m.paged_tokens
-                   if pins and rp.device in m.copies else 0)
-        if pins and not matched:
-            self.radix.release(pins.pop())
-        self.stats.radix_hit_tokens += matched
-        if matched:
-            self.stats.radix_hit_requests += 1
-        # page dedup: share the matched copy's pages with this slot
-        # instead of holding private duplicates — the slot's own
-        # leading pages return to the pool and its booking shrinks.
-        # The backing pin (held for the request's lifetime) is what
-        # keeps the shared pages resident.
-        dedup_n = 0
-        if self.dedup_on and matched:
-            shared = m.copies[rp.device][: matched
-                                         // self.cfg.sac.page_size]
-            dedup_n = self.sac.dedup_match(req.request_id, shared)
-            if dedup_n:
-                self.stats.dedup_shared_pages = \
-                    self.sac.dedup_shared_pages
-        # replica-aware reads (PR 7): the devices holding a copy of the
-        # matched prefix and the fraction of this slot's reads in the
-        # prefix region — step() re-picks the least-pressured copy
-        # every step (the backing pin keeps every copy resident)
-        copies, frac = (), 0.0
-        if self.replica_reads_on and matched:
-            copies = tuple(sorted(m.copies))
-            frac = matched / max(len(prompt), 1)
-        return _PrefillJob(req=req, prompt=prompt, matched=matched,
-                           pins=pins, rp=rp, dedup_n=dedup_n,
-                           copies=copies, frac=frac)
+        with self.spans.span("Engine.radix", request_id=req.request_id):
+            prompt = req.prompt_tokens[: req.context_len]
+            toks = prompt.tolist()
+            # radix prefix lookup — PAGE-granular reuse (crediting the
+            # raw token walk would count prefix tokens no cached page
+            # backs).  The BACKING node's path is pinned immediately so
+            # the pool-pressure eviction inside place() cannot free the
+            # pages we are about to reuse.
+            m = self.radix.match(toks) if self.radix is not None else None
+            pins: List[list] = []
+            if m is not None and m.hit:
+                pins.append(list(m.pin_tokens))
+                self.radix.pin(pins[-1])
+                if self.replicate_on:
+                    # the pin above keeps the node alive through the
+                    # copy; a successful replication re-matches so the
+                    # placer sees every copy (same node, same pin path)
+                    m2 = self._maybe_replicate(m, toks, len(prompt))
+                    if m2 is not None and m2.hit:
+                        m = m2
+            bonus_s = (self._locality_bonus_s(len(prompt), m.paged_tokens)
+                       if pins else 0.0)
+            rp = self.sac.place(req.request_id, len(prompt) + req.output_len,
+                                affinity=sorted(m.copies) if pins else None,
+                                affinity_s=bonus_s)
+            if rp is None:
+                for p in pins:
+                    self.radix.release(p)
+                return None
+            req.dispatch_s = self.clock_s
+            req.pool_device = rp.device
+            # reuse is only real on a device holding a copy of the
+            # cached pages (off-device, the prefix would cross two
+            # fabric links — no better than recomputing); radix_affinity
+            # placement + replication are what make this coincide
+            matched = (m.paged_tokens
+                       if pins and rp.device in m.copies else 0)
+            if pins and not matched:
+                self.radix.release(pins.pop())
+            self.stats.radix_hit_tokens += matched
+            if matched:
+                self.stats.radix_hit_requests += 1
+            # page dedup: share the matched copy's pages with this slot
+            # instead of holding private duplicates — the slot's own
+            # leading pages return to the pool and its booking shrinks.
+            # The backing pin (held for the request's lifetime) is what
+            # keeps the shared pages resident.
+            dedup_n = 0
+            if self.dedup_on and matched:
+                shared = m.copies[rp.device][: matched
+                                             // self.cfg.sac.page_size]
+                dedup_n = self.sac.dedup_match(req.request_id, shared)
+                if dedup_n:
+                    self.stats.dedup_shared_pages = \
+                        self.sac.dedup_shared_pages
+            # replica-aware reads: the devices holding a copy of the
+            # matched prefix and the fraction of this slot's reads in the
+            # prefix region — step() re-picks the least-pressured copy
+            # every step (the backing pin keeps every copy resident)
+            copies, frac = (), 0.0
+            if self.replica_reads_on and matched:
+                copies = tuple(sorted(m.copies))
+                frac = matched / max(len(prompt), 1)
+            return _PrefillJob(req=req, prompt=prompt, matched=matched,
+                               pins=pins, rp=rp, dedup_n=dedup_n,
+                               copies=copies, frac=frac)
 
     def _complete_prefill(self, s: int, job: _PrefillJob):
         """Splice a finished prefill into slot ``s`` — the jitted
@@ -750,10 +767,13 @@ class Engine:
         modeled timing and fabric traffic, never decoded tokens."""
         req, prompt, rp = job.req, job.prompt, job.rp
         matched = job.matched
-        st, _ = self._prefill_one(self.params, prompt[None, :])
+        with self.spans.span("Engine.prefill", request_id=req.request_id,
+                             tokens=len(prompt)):
+            st, _ = self._prefill_one(self.params, prompt[None, :])
         st = dict(st)
         warm_idx = st.pop("warm_idx", None)
-        self._splice_state(s, st, len(prompt))
+        with self.spans.span("Engine.splice", request_id=req.request_id):
+            self._splice_state(s, st, len(prompt))
         page_tokens = (len(prompt) // self.cfg.sac.page_size) \
             * self.cfg.sac.page_size
         keep = 0
@@ -779,34 +799,36 @@ class Engine:
         # prefill-time warm-up: seed the recycled (cold) lane from the
         # radix-reused prefix tail + top-scoring prompt entries
         if self.planner is not None:
-            plan = self.planner.warmup_plan(
-                None if warm_idx is None else warm_idx[:, 0],
-                matched, len(prompt))
-            if plan is not None and self.arbiter is not None:
-                # warm-up arbitration: the prefill warm burst draws
-                # from the same per-device link budget as decode
-                # speculation — its hide window is the (radix-
-                # shortened) prefill compute this burst rides behind
-                w_cap = self.arbiter.grant_warmup(
-                    self.profile.prefill_s(len(prompt) - matched),
-                    self._last_demand_s, req.pool_device,
-                    int(plan.idx.shape[1]))
-                plan = cap_warmup(plan, w_cap)
-            if plan is not None:
-                hot, n_ins = self._warm(
-                    self.state["hot_buf"], self.state["kv_pool"],
-                    jnp.int32(s), plan.idx, plan.valid)
-                self.state["hot_buf"] = hot
-                n_ins = int(n_ins)
-                if n_ins:
-                    # deliberately UNkeyed: warm seeds cannot have
-                    # been demand-hit yet, so keying them would book
-                    # (n_ins, 0) against the request and tank its
-                    # precision right at its first grants — the
-                    # cold-start starvation the weighting must avoid
-                    self.sac.traffic.record_prefetch(n_ins, 0)
-                    self.sac.prefetch_fetch_time(
-                        n_ins, device=req.pool_device)
+            with self.spans.span("Engine.warm", request_id=req.request_id):
+                plan = self.planner.warmup_plan(
+                    None if warm_idx is None
+                    else self._read(warm_idx[:, 0]),
+                    matched, len(prompt))
+                if plan is not None and self.arbiter is not None:
+                    # warm-up arbitration: the prefill warm burst draws
+                    # from the same per-device link budget as decode
+                    # speculation — its hide window is the (radix-
+                    # shortened) prefill compute this burst rides behind
+                    w_cap = self.arbiter.grant_warmup(
+                        self.profile.prefill_s(len(prompt) - matched),
+                        self._last_demand_s, req.pool_device,
+                        int(plan.idx.shape[1]))
+                    plan = cap_warmup(plan, w_cap)
+                if plan is not None:
+                    hot, n_ins = self._warm(
+                        self.state["hot_buf"], self.state["kv_pool"],
+                        jnp.int32(s), plan.idx, plan.valid)
+                    self.state["hot_buf"] = hot
+                    n_ins = int(self._read(n_ins))
+                    if n_ins:
+                        # deliberately UNkeyed: warm seeds cannot have
+                        # been demand-hit yet, so keying them would book
+                        # (n_ins, 0) against the request and tank its
+                        # precision right at its first grants — the
+                        # cold-start starvation the weighting must avoid
+                        self.sac.traffic.record_prefetch(n_ins, 0)
+                        self.sac.prefetch_fetch_time(
+                            n_ins, device=req.pool_device)
         self.slot_req[s] = req
         self.slot_tokens[s] = [int(prompt[-1])]
 
@@ -836,50 +858,51 @@ class Engine:
         Mode dispatch goes through the shared :class:`PrefillSchedule`
         (serving/policy/prefill.py), the same object the replay's
         ``fill()`` reads."""
-        self._shed_waiting()
-        if self.prefill_schedule.disagg:
-            adopted = self._adopt_handoffs()
-            started = self._start_prefill_lanes()
-            return adopted or started
-        if self.prefill_schedule.chunked:
-            created = self._create_chunk_jobs()
-            advanced = self._advance_chunk_jobs()
-            return created or advanced
-        # monolithic colocated: the seed path + the arrival gate
-        progressed = False
-        for s in range(self.slots):
-            if self.slot_req[s] is not None:
-                continue
-            eligible = self._eligible_indices()
-            if not eligible:
-                break
-            req = self.queue.pop(self._pick_queue_index(eligible))
-            job = self._admit_request(req)
-            if job is None:
-                self._requeue_unplaceable(req)
-                break
-            issued0 = self.stats.traffic.fabric_time_s
-            # charge the pool write for the NON-matched tokens only (the
-            # matched pages' KV is copied device-locally from the cached
-            # prefix, never crossing the fabric), against the request's
-            # own pool link — the arbiter's demand signal must see
-            # prefill pressure on the device it actually loads
-            self.sac.write_back_time(job.effective,
-                                     device=req.pool_device,
-                                     key=req.request_id)
-            self._complete_prefill(s, job)
-            # virtual clock: prefill compute — a genuine radix hit skips
-            # the matched prefix's recompute, so the modeled prefill (and
-            # with it TTFT) shortens; fill-time fabric traffic (pool
-            # write + warm-up) hides behind it when overlap is on
-            t_prefill = self.profile.prefill_s(job.effective)
-            if self.overlap_on:
-                exposed = self.sac.traffic.drain_overlap(t_prefill)
-            else:
-                exposed = self.stats.traffic.fabric_time_s - issued0
-            self.clock_s += t_prefill + exposed
-            progressed = True
-        return progressed
+        with self.spans.span("Engine.admit"):
+            self._shed_waiting()
+            if self.prefill_schedule.disagg:
+                adopted = self._adopt_handoffs()
+                started = self._start_prefill_lanes()
+                return adopted or started
+            if self.prefill_schedule.chunked:
+                created = self._create_chunk_jobs()
+                advanced = self._advance_chunk_jobs()
+                return created or advanced
+            # monolithic colocated: the seed path + the arrival gate
+            progressed = False
+            for s in range(self.slots):
+                if self.slot_req[s] is not None:
+                    continue
+                eligible = self._eligible_indices()
+                if not eligible:
+                    break
+                req = self.queue.pop(self._pick_queue_index(eligible))
+                job = self._admit_request(req)
+                if job is None:
+                    self._requeue_unplaceable(req)
+                    break
+                issued0 = self.stats.traffic.fabric_time_s
+                # charge the pool write for the NON-matched tokens only (the
+                # matched pages' KV is copied device-locally from the cached
+                # prefix, never crossing the fabric), against the request's
+                # own pool link — the arbiter's demand signal must see
+                # prefill pressure on the device it actually loads
+                self.sac.write_back_time(job.effective,
+                                         device=req.pool_device,
+                                         key=req.request_id)
+                self._complete_prefill(s, job)
+                # virtual clock: prefill compute — a genuine radix hit skips
+                # the matched prefix's recompute, so the modeled prefill (and
+                # with it TTFT) shortens; fill-time fabric traffic (pool
+                # write + warm-up) hides behind it when overlap is on
+                t_prefill = self.profile.prefill_s(job.effective)
+                if self.overlap_on:
+                    exposed = self.sac.traffic.drain_overlap(t_prefill)
+                else:
+                    exposed = self.stats.traffic.fabric_time_s - issued0
+                self.clock_s += t_prefill + exposed
+                progressed = True
+            return progressed
 
     def _create_chunk_jobs(self) -> bool:
         """Chunked colocated admission: bind an arrived request to each
@@ -1045,7 +1068,13 @@ class Engine:
 
         ``now`` defaults to the engine's virtual clock (advanced by the
         modeled compute + exposed fabric of this step); passing an
-        explicit value only overrides the request timestamps."""
+        explicit value only overrides the request timestamps.  Each
+        phase of the step is a span of ``self.spans`` inside the step's
+        own ``Engine.step``."""
+        with self.spans.span("Engine.step", step=self.stats.steps):
+            return self._step(now)
+
+    def _step(self, now: Optional[float]) -> List[Request]:
         clock0 = self.clock_s       # a slot decoding through this step
                                     # sees the WHOLE step() wall time —
                                     # chunk stalls included — as its gap
@@ -1063,17 +1092,51 @@ class Engine:
                     self._fill_slots()
             if not any(r is not None for r in self.slot_req):
                 return []
-        tokens = jnp.array(
-            [(toks[-1] if toks else 0) for toks in self.slot_tokens],
-            jnp.int32)
-        prev_len = np.asarray(self.state["cache_len"])
-        occupied = [s for s in range(self.slots) if self.slot_req[s]]
-        t_comp = self.step_compute_s(len(occupied))
-        # replica-aware read choice (PR 7): slot -> (read device, prefix
-        # read fraction).  Re-evaluated every step from the bottleneck-
-        # projected pressure feed — the copy choice is NOT frozen at
-        # placement.  With replica_reads off this is (own device, 0.0)
-        # and everything below is bit-identical to the flat path.
+        with self.spans.span("Engine.prepare"):
+            tokens = jnp.array(
+                [(toks[-1] if toks else 0) for toks in self.slot_tokens],
+                jnp.int32)
+            prev_len = self._read(self.state["cache_len"])
+            occupied = [s for s in range(self.slots) if self.slot_req[s]]
+            t_comp = self.step_compute_s(len(occupied))
+            reads = self._read_choice(occupied)
+            budgets = (self._grant(occupied, reads, t_comp)
+                       if self.arbiter is not None else None)
+        with self.spans.span("Engine.decode"):
+            if budgets is not None:
+                self.state, logits = self._decode(
+                    self.params, self.state, tokens, jnp.asarray(budgets))
+            else:
+                self.state, logits = self._decode(self.params, self.state,
+                                                  tokens)
+        with self.spans.span("Engine.wait_token"):
+            next_tokens = self._read(jnp.argmax(logits, axis=-1))
+        self.stats.steps += 1
+        # the first decode step closes the warm-up seeding window:
+        # the tracker's first observe() below includes the warm-up
+        # traffic, so leaving the seed on would double-count it
+        self.warm_seed.deactivate()
+        counts = None
+        sac_step = self.cfg.sac.enabled and self.model.mode == "sac"
+        if sac_step and self.device_buffer:
+            with self.spans.span("Engine.counters"):
+                counts = self._read_counters(occupied)
+        with self.spans.span("Engine.account"):
+            exposed = self._account(occupied, reads, counts, prev_len,
+                                    t_comp, sac_step)
+        self._maybe_resize()
+        self.clock_s += t_comp + exposed
+        if now is None:
+            now = self.clock_s
+        with self.spans.span("Engine.finish"):
+            return self._finish(occupied, next_tokens, now, clock0)
+
+    def _read_choice(self, occupied: List[int]) -> Dict[int, tuple]:
+        """Replica-aware read choice: slot -> (own device, read
+        device, prefix read fraction).  Re-evaluated every step from the
+        bottleneck-projected pressure feed — the copy choice is NOT
+        frozen at placement.  With replica_reads off this is (own, own,
+        0.0) and the accounting is bit-identical to the flat path."""
         reads: Dict[int, tuple] = {}
         pres = (list(self.sac.placer.device_pressure())
                 if self.replica_reads_on else None)
@@ -1103,109 +1166,111 @@ class Engine:
                 if own < len(pres):
                     pres[own] += (1.0 - frac) * est_s
             reads[s] = (own, rd, frac)
-        if self.arbiter is not None:
-            # cross-request budget arbitration: last step's measured
-            # per-device demand backlog shapes this step's speculation;
-            # with precision weighting on, each slot's measured prefetch
-            # precision (per-request TrafficStats attribution) tilts its
-            # share of the device budget
-            dev_slots: Dict[int, List[int]] = {}
-            precision = None
-            if self.arbiter.cfg.precision_weighted:
-                precision = {}
+        return reads
+
+    def _grant(self, occupied: List[int], reads: Dict[int, tuple],
+               t_comp: float) -> np.ndarray:
+        """Cross-request budget arbitration: last step's measured
+        per-device demand backlog shapes this step's speculation; with
+        precision weighting on, each slot's measured prefetch precision
+        (per-request TrafficStats attribution) tilts its share of the
+        device budget.  Returns the per-slot granted widths."""
+        dev_slots: Dict[int, List[int]] = {}
+        precision = None
+        if self.arbiter.cfg.precision_weighted:
+            precision = {}
+        for s in occupied:
+            req = self.slot_req[s]
+            # group under the slot's READ device: a replica-
+            # redirected slot's granted fetches flow on the chosen
+            # copy's path, so its budget must be consumed there
+            dev_slots.setdefault(reads[s][1], []).append(s)
+            if precision is not None:
+                precision[s] = self.stats.traffic.request_precision(
+                    req.request_id)
+        self.last_grants = self.arbiter.grant(
+            t_comp, self._last_demand_s, dev_slots,
+            precision=precision)
+        budgets = np.zeros((self.slots,), np.int32)
+        for s, w in self.last_grants.items():
+            budgets[s] = w
+            self._grant_sum += w
+            self._grant_n += 1
+        return budgets
+
+    def _read_counters(self, occupied: List[int]) -> Dict[str, np.ndarray]:
+        """The step's in-graph hot-tier (and, with prefetch, speculation)
+        counters on the host; adds the per-layer split to the stats (the
+        LayerSizer's miss-rate signal)."""
+        st = self.state
+        counts = {"hits": self._read(st["buf_hits"]),
+                  "misses": self._read(st["buf_misses"])}
+        self.stats.layer_hits += \
+            self._read(st["buf_hits_l"])[:, occupied].sum(1)
+        self.stats.layer_misses += \
+            self._read(st["buf_misses_l"])[:, occupied].sum(1)
+        if self.prefetch:
+            counts["pf_ins"] = self._read(st["pf_inserted"])
+            counts["pf_use"] = self._read(st["pf_useful"])
+        return counts
+
+    def _account(self, occupied: List[int], reads: Dict[int, tuple],
+                 counts: Optional[Dict[str, np.ndarray]],
+                 prev_len: np.ndarray, t_comp: float,
+                 sac_step: bool) -> float:
+        """Modelled fabric accounting per occupied slot, then the demand
+        feedback; returns the step's exposed fabric seconds."""
+        issued0 = self.stats.traffic.fabric_time_s
+        if counts is not None:
+            # miss-only charging: the jitted step measured per-slot
+            # hot-tier residency; only misses cross the fabric
+            hits, misses = counts["hits"], counts["misses"]
             for s in occupied:
                 req = self.slot_req[s]
-                # group under the slot's READ device: a replica-
-                # redirected slot's granted fetches flow on the chosen
-                # copy's path, so its budget must be consumed there
-                dev_slots.setdefault(reads[s][1], []).append(s)
-                if precision is not None:
-                    precision[s] = self.stats.traffic.request_precision(
-                        req.request_id)
-            self.last_grants = self.arbiter.grant(
-                t_comp, self._last_demand_s, dev_slots,
-                precision=precision)
-            budgets = np.zeros((self.slots,), np.int32)
-            for s, w in self.last_grants.items():
-                budgets[s] = w
-                self._grant_sum += w
-                self._grant_n += 1
-            self.state, logits = self._decode(
-                self.params, self.state, tokens, jnp.asarray(budgets))
-        else:
-            self.state, logits = self._decode(self.params, self.state,
-                                              tokens)
-        next_tokens = np.asarray(jnp.argmax(logits, axis=-1))
-        self.stats.steps += 1
-        # the first decode step closes the PR 7 warm-up seeding window:
-        # the tracker's first observe() below includes the warm-up
-        # traffic, so leaving the seed on would double-count it
-        self.warm_seed.deactivate()
-
-        # fabric accounting per occupied slot
-        issued0 = self.stats.traffic.fabric_time_s
-        if self.cfg.sac.enabled and self.model.mode == "sac":
-            if self.device_buffer:
-                # miss-only charging: the jitted step measured per-slot
-                # hot-tier residency; only misses cross the fabric
-                hits = np.asarray(self.state["buf_hits"])
-                misses = np.asarray(self.state["buf_misses"])
-                # per-layer split (LayerSizer miss-rate signal)
-                self.stats.layer_hits += \
-                    np.asarray(self.state["buf_hits_l"])[:, occupied].sum(1)
-                self.stats.layer_misses += \
-                    np.asarray(self.state["buf_misses_l"])[:, occupied] \
-                    .sum(1)
-                if self.prefetch:
-                    pf_ins = np.asarray(self.state["pf_inserted"])
-                    pf_use = np.asarray(self.state["pf_useful"])
-                for s in occupied:
-                    req = self.slot_req[s]
-                    dev, read_dev, frac = reads[s]
-                    self.sac.traffic.record_hits(int(hits[s]),
-                                                 int(misses[s]))
-                    n_miss = int(misses[s])
-                    if n_miss:
-                        # keyed: the request's own demand share, so the
-                        # pressure feed can subtract it at departure.
-                        # The prefix-region share of the misses reads
-                        # the step's chosen replica copy; the rest stays
-                        # on the slot's own device (frac == 0 charges
-                        # everything there — the flat path, unchanged).
-                        n_pfx = min(int(round(n_miss * frac)), n_miss)
-                        if n_pfx:
-                            self.sac.sparse_fetch_time(
-                                n_pfx, device=read_dev,
-                                key=req.request_id)
-                        if n_miss - n_pfx:
-                            self.sac.sparse_fetch_time(
-                                n_miss - n_pfx, device=dev,
-                                key=req.request_id)
-                    if self.prefetch:
-                        # measured speculation outcomes (in-graph pf_*
-                        # counters): issued entries cross the fabric as
-                        # prefetch traffic; useful ones were demand hits.
-                        # Keyed by request so the arbiter's precision
-                        # weighting sees per-request precision.  Charged
-                        # to the READ device — the same path the grant
-                        # that authorized these entries was budgeted on.
-                        self.sac.traffic.record_prefetch(
-                            int(pf_ins[s]), int(pf_use[s]),
+                dev, read_dev, frac = reads[s]
+                self.sac.traffic.record_hits(int(hits[s]), int(misses[s]))
+                n_miss = int(misses[s])
+                if n_miss:
+                    # keyed: the request's own demand share, so the
+                    # pressure feed can subtract it at departure.  The
+                    # prefix-region share of the misses reads the step's
+                    # chosen replica copy; the rest stays on the slot's
+                    # own device (frac == 0 charges everything there —
+                    # the flat path, unchanged).
+                    n_pfx = min(int(round(n_miss * frac)), n_miss)
+                    if n_pfx:
+                        self.sac.sparse_fetch_time(
+                            n_pfx, device=read_dev, key=req.request_id)
+                    if n_miss - n_pfx:
+                        self.sac.sparse_fetch_time(
+                            n_miss - n_pfx, device=dev,
                             key=req.request_id)
-                        if int(pf_ins[s]):
-                            self.sac.prefetch_fetch_time(int(pf_ins[s]),
-                                                         device=read_dev)
-            else:
-                # cold-read convention: every step is charged the full
-                # top-k transfer per layer
-                k = min(self.cfg.sac.topk, self.max_ctx)
-                n_layers = max(getattr(self.model, "n_kv", 1), 1)
-                for s in occupied:
-                    req = self.slot_req[s]
-                    n = min(k * n_layers, int(prev_len[s]) * n_layers or 1)
-                    self.sac.sparse_fetch_time(
-                        n, device=self.sac.device_of(req.request_id),
+                if self.prefetch:
+                    # measured speculation outcomes (in-graph pf_*
+                    # counters): issued entries cross the fabric as
+                    # prefetch traffic; useful ones were demand hits.
+                    # Keyed by request so the arbiter's precision
+                    # weighting sees per-request precision.  Charged to
+                    # the READ device — the same path the grant that
+                    # authorized these entries was budgeted on.
+                    pf_ins, pf_use = counts["pf_ins"], counts["pf_use"]
+                    self.sac.traffic.record_prefetch(
+                        int(pf_ins[s]), int(pf_use[s]),
                         key=req.request_id)
+                    if int(pf_ins[s]):
+                        self.sac.prefetch_fetch_time(int(pf_ins[s]),
+                                                     device=read_dev)
+        elif sac_step:
+            # cold-read convention: every step is charged the full
+            # top-k transfer per layer
+            k = min(self.cfg.sac.topk, self.max_ctx)
+            n_layers = max(getattr(self.model, "n_kv", 1), 1)
+            for s in occupied:
+                req = self.slot_req[s]
+                n = min(k * n_layers, int(prev_len[s]) * n_layers or 1)
+                self.sac.sparse_fetch_time(
+                    n, device=self.sac.device_of(req.request_id),
+                    key=req.request_id)
         # issued vs exposed: drain the per-device queues against this
         # step's compute window (exposed == issued when overlap is off)
         if self.overlap_on:
@@ -1220,16 +1285,21 @@ class Engine:
             self.stats.traffic,
             [self.slot_req[s].request_id for s in occupied])
         self.sac.note_pressure_update()
-        # online LayerSizer re-sizing: every resize_interval steps the
-        # measured per-layer miss rates re-apportion the hot tier by
-        # re-marking the DISABLED sentinels in place — displaced entries
-        # are evicted, resident ones survive, tokens never change.  The
-        # sizer consumes the rates of THIS interval (deltas against the
-        # last resize's snapshot), not lifetime averages — a lifetime
-        # signal goes stale after the first resize or a demand shift and
-        # the loop would stop adapting.
-        if (self._sizer is not None and self.resize_interval
+        return exposed
+
+    def _maybe_resize(self):
+        """Online LayerSizer re-sizing: every resize_interval steps the
+        measured per-layer miss rates re-apportion the hot tier by
+        re-marking the DISABLED sentinels in place — displaced entries
+        are evicted, resident ones survive, tokens never change.  The
+        sizer consumes the rates of THIS interval (deltas against the
+        last resize's snapshot), not lifetime averages — a lifetime
+        signal goes stale after the first resize or a demand shift and
+        the loop would stop adapting."""
+        if not (self._sizer is not None and self.resize_interval
                 and self.stats.steps % self.resize_interval == 0):
+            return
+        with self.spans.span("Engine.resize"):
             rates = self._interval_miss_rates()
             # hysteresis (cfg.sac.resize_epsilon): when no layer's
             # per-interval miss rate moved by more than epsilon since
@@ -1244,19 +1314,19 @@ class Engine:
                     and max(abs(r - p) for r, p in
                             zip(rates, self._resize_rates_ref)) < eps):
                 self.stats.resize_skips += 1
-            else:
-                new_sizes = self._sizer.sizes(rates)
-                self._resize_rates_ref = rates
-                if new_sizes != list(self.buffer_sizes):
-                    self.stats.resizes += 1
-                    self.state = dict(self.state)
-                    self.state["hot_buf"] = hisparse.resize_layers(
-                        self.state["hot_buf"], new_sizes)
-                    self.buffer_sizes = new_sizes
-        self.clock_s += t_comp + exposed
-        if now is None:
-            now = self.clock_s
+                return
+            new_sizes = self._sizer.sizes(rates)
+            self._resize_rates_ref = rates
+            if new_sizes != list(self.buffer_sizes):
+                self.stats.resizes += 1
+                self.state = dict(self.state)
+                self.state["hot_buf"] = hisparse.resize_layers(
+                    self.state["hot_buf"], new_sizes)
+                self.buffer_sizes = new_sizes
 
+    def _finish(self, occupied: List[int], next_tokens: np.ndarray,
+                now: float, clock0: float) -> List[Request]:
+        """Append each slot's token; release the requests that are done."""
         finished = []
         for s in occupied:
             req = self.slot_req[s]

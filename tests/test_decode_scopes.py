@@ -1,0 +1,60 @@
+"""The named scopes of the decode program label its ops and change none of
+them (reduced config, lowered on the CPU)."""
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+
+from repro.configs import get_config
+from repro.models.model import build_model
+
+SCOPES = ("indexer", "topk", "gather", "hot_tier", "attention", "pool_slice",
+          "pool_write", "mlp", "lm_head", "layers")
+
+
+def _lower():
+    """The reduced decode, built as ``Engine(..., prefetch=True)`` builds
+    it (a fresh model each call, so nothing is reused from a trace made
+    under other scopes)."""
+    cfg = get_config("qwen2-1.5b").reduced()
+    sac = cfg.sac
+    model = build_model(cfg, mode="sac", opts={
+        "prefetch_width": sac.prefetch_width,
+        "score_margin": sac.score_margin, "warmup_w": sac.warmup_entries})
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = model.serve_state_shapes(2, 64,
+                                     device_buffer=sac.device_buffer_size)
+    tokens = jax.ShapeDtypeStruct((2,), jnp.int32)
+    return jax.jit(model.decode).lower(params, state, tokens)
+
+
+def _program(compiled: str) -> str:
+    """The compiled module's computations with their metadata taken out
+    (the header tables of source files and stack frames go with it)."""
+    body = compiled[compiled.index("\n%"):]
+    return re.sub(r", metadata=\{[^}]*\}", "", body)
+
+
+def _scopes(lowered) -> set:
+    """The scopes on the op name paths of the lowered module (the last
+    part of a path names the primitive)."""
+    text = lowered.as_text(dialect="hlo", debug_info=True)
+    return {part for path in re.findall(r'op_name="([^"]*)"', text)
+            for part in path.split("/")[:-1]}
+
+
+def test_every_scope_labels_decode_ops_and_changes_no_op(monkeypatch):
+    scoped = _lower()
+    assert set(SCOPES) <= _scopes(scoped)
+    compiled = scoped.compile().as_text()
+    assert re.search(r'op_name="[^"]*/hot_tier/', compiled)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    plain = _lower()
+    plain_compiled = plain.compile().as_text()
+    assert not set(SCOPES) & _scopes(plain)
+    # the same program: the lowering without locations, and the compiled
+    # module without metadata
+    assert scoped.as_text() == plain.as_text()
+    assert _program(compiled) == _program(plain_compiled)
