@@ -11,8 +11,13 @@ jit/vmap-able and property-testable:
   2. **LRU eviction** — pick the least-recently-used resident slots that
      are *not* part of the current top-k as eviction victims (empty slots
      are filled first);
-  3. **page-table update + fetch** — unmap victims, map fetched pages in,
-     write the fetched data, bump recency clocks.
+  3. **page-table update** — unmap victims, map fetched pages in, bump
+     recency clocks.
+
+The tier tracks residency only: which position each slot holds, never the
+entry's values.  Every decode path fetches all top-k rows from the pool
+before the tier is consulted, so the values a hit would serve are the
+fetched ones, and a stored copy would be read by nothing.
 
 All scatters use a padding "sink" row (index ``buf``/``S``) for inactive
 lanes so no two active lanes ever write the same slot — scatter-set order
@@ -48,7 +53,6 @@ class BufferState(NamedTuple):
     / ``pf_used`` are cumulative per-request counters, so prefetch
     precision is measured *in-graph* (``wasted == inserted - used``).
     """
-    entries: jnp.ndarray      # [B, buf, d]   cached KV entries
     slot_pos: jnp.ndarray     # [B, buf]      global position held by slot (-1 empty)
     page_table: jnp.ndarray   # [B, S]        position -> slot (-1 not resident)
     last_use: jnp.ndarray     # [B, buf]      LRU clocks
@@ -58,10 +62,8 @@ class BufferState(NamedTuple):
     pf_used: jnp.ndarray      # [B]           cumulative prefetched-then-hit
 
 
-def init_buffer(batch: int, buf_size: int, seq_len: int, entry_dim: int,
-                dtype=jnp.bfloat16) -> BufferState:
+def init_buffer(batch: int, buf_size: int, seq_len: int) -> BufferState:
     return BufferState(
-        entries=jnp.zeros((batch, buf_size, entry_dim), dtype),
         slot_pos=jnp.full((batch, buf_size), EMPTY),
         page_table=jnp.full((batch, seq_len), EMPTY),
         last_use=jnp.zeros((batch, buf_size), jnp.int32),
@@ -79,13 +81,12 @@ def lookup(state: BufferState, idx: jnp.ndarray
     return slots, slots >= 0
 
 
-def _swap_in_one(entries, slot_pos, page_table, last_use, clock, pf_flag,
-                 idx, fetched, valid):
+def _swap_in_one(slot_pos, page_table, last_use, clock, pf_flag, idx,
+                 valid):
     """Single-request swap-in (vmapped over B).
 
     idx: [k] positions requested this step (always in [0, S));
-    fetched: [k, d] pool values for all of them (hits keep their buffered
-    copy — static shapes); valid: [k] mask of real lanes.
+    valid: [k] mask of real lanes.
 
     Note: if ``k > buf`` overflow misses stay unbuffered; accounting of
     hits is exact because reads happen before the swap-in.
@@ -133,11 +134,6 @@ def _swap_in_one(entries, slot_pos, page_table, last_use, clock, pf_flag,
     sp = sp.at[assign].set(jnp.where(fillable, idx, EMPTY))
     slot_pos = sp[:buf]
 
-    ent = jnp.concatenate(
-        [entries, jnp.zeros((1, entries.shape[-1]), entries.dtype)])
-    ent = ent.at[assign].set(fetched.astype(entries.dtype))
-    entries = ent[:buf]
-
     touched = jnp.where(hit, slots, assign)                # in [0, buf]
     lu = jnp.concatenate([last_use, jnp.zeros((1,), jnp.int32)])
     last_use = lu.at[touched].set(clock)[:buf]
@@ -151,46 +147,28 @@ def _swap_in_one(entries, slot_pos, page_table, last_use, clock, pf_flag,
     pf = jnp.concatenate([pf_flag & ~hit_mask, jnp.zeros((1,), bool)])
     pf_flag = pf.at[assign].set(False)[:buf]
 
-    return (entries, slot_pos, page_table, last_use, pf_flag, pf_used,
+    return (slot_pos, page_table, last_use, pf_flag, pf_used,
             hit.astype(jnp.int32).sum(), miss.astype(jnp.int32).sum())
 
 
-def swap_in(state: BufferState, idx: jnp.ndarray, fetched: jnp.ndarray,
-            valid: jnp.ndarray) -> Tuple[BufferState, jnp.ndarray, jnp.ndarray]:
-    """Batched swap-in.  idx: [B,k]; fetched: [B,k,d]; valid: [B,k].
+def swap_in(state: BufferState, idx: jnp.ndarray, valid: jnp.ndarray
+            ) -> Tuple[BufferState, jnp.ndarray, jnp.ndarray]:
+    """Batched swap-in of a demand read.  idx: [B,k]; valid: [B,k].
 
-    Returns (state', hits [B], misses [B]).
+    Counts each valid lane resident before the step as a hit and each
+    first occurrence of a non-resident one as a miss, then maps the misses
+    in.  The values read are the caller's pool fetch either way — the hot
+    tier changes *traffic*, never results.  Returns (state', hits [B],
+    misses [B]).
     """
     clock = state.clock + 1
-    (entries, slot_pos, page_table, last_use, pf_flag, pf_used, hits,
+    (slot_pos, page_table, last_use, pf_flag, pf_used, hits,
      misses) = jax.vmap(_swap_in_one)(
-        state.entries, state.slot_pos, state.page_table,
-        state.last_use, clock, state.pf_flag, idx, fetched, valid)
-    return (BufferState(entries, slot_pos, page_table, last_use, clock,
-                        pf_flag, state.pf_inserted,
-                        state.pf_used + pf_used),
+        state.slot_pos, state.page_table, state.last_use, clock,
+        state.pf_flag, idx, valid)
+    return (BufferState(slot_pos, page_table, last_use, clock, pf_flag,
+                        state.pf_inserted, state.pf_used + pf_used),
             hits, misses)
-
-
-def read_through(state: BufferState, idx: jnp.ndarray, fetched: jnp.ndarray,
-                 valid: jnp.ndarray
-                 ) -> Tuple[jnp.ndarray, BufferState, jnp.ndarray, jnp.ndarray]:
-    """Serve idx from the buffer where resident, else from ``fetched``
-    (pool values), updating the buffer.  Returns (values [B,k,d], state',
-    hits [B], misses [B]).
-
-    Values are bit-identical with or without the buffer — the hot tier
-    changes *traffic*, never results (the pool is authoritative; entries
-    are immutable once written).
-    """
-    slots, hit = lookup(state, idx)
-    buffered = jnp.take_along_axis(
-        state.entries,
-        jnp.clip(slots, 0, state.entries.shape[1] - 1)[..., None], axis=1)
-    vals = jnp.where((hit & valid)[..., None], buffered.astype(fetched.dtype),
-                     fetched)
-    new_state, hits, misses = swap_in(state, idx, fetched, valid)
-    return vals, new_state, hits, misses
 
 
 # ---------------------------------------------------------------------------
@@ -198,8 +176,8 @@ def read_through(state: BufferState, idx: jnp.ndarray, fetched: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 
-def _warm_insert_one(entries, slot_pos, page_table, last_use, clock, pf_flag,
-                     idx, vals, valid):
+def _warm_insert_one(slot_pos, page_table, last_use, clock, pf_flag, idx,
+                     valid):
     """Single-request warm insert (vmapped over B).
 
     Insert-without-read: positions already resident are skipped (no hit
@@ -246,54 +224,45 @@ def _warm_insert_one(entries, slot_pos, page_table, last_use, clock, pf_flag,
     sp = sp.at[assign].set(jnp.where(fill, idx, EMPTY))
     slot_pos = sp[:buf]
 
-    ent = jnp.concatenate(
-        [entries, jnp.zeros((1, entries.shape[-1]), entries.dtype)])
-    ent = ent.at[assign].set(vals.astype(entries.dtype))
-    entries = ent[:buf]
-
     lu = jnp.concatenate([last_use, jnp.zeros((1,), jnp.int32)])
     last_use = lu.at[assign].set(clock)[:buf]
 
     pf = jnp.concatenate([pf_flag, jnp.zeros((1,), bool)])
     pf_flag = pf.at[assign].set(fill)[:buf]
 
-    return (entries, slot_pos, page_table, last_use, pf_flag,
+    return (slot_pos, page_table, last_use, pf_flag,
             fill.astype(jnp.int32).sum())
 
 
-def warm_insert(state: BufferState, idx: jnp.ndarray, vals: jnp.ndarray,
-                valid: jnp.ndarray) -> Tuple[BufferState, jnp.ndarray]:
-    """Batched warm insert.  idx: [B, w]; vals: [B, w, d]; valid: [B, w].
+def warm_insert(state: BufferState, idx: jnp.ndarray, valid: jnp.ndarray
+                ) -> Tuple[BufferState, jnp.ndarray]:
+    """Batched warm insert.  idx: [B, w]; valid: [B, w].
 
-    Inserts pool values into the hot tier WITHOUT serving a read — no
-    hit/miss is counted, current-step hits are never evicted, and already
-    resident positions are skipped.  Returns (state', inserted [B]); the
+    Makes pool positions resident WITHOUT serving a read — no hit/miss is
+    counted, current-step hits are never evicted, and already resident
+    positions are skipped.  Returns (state', inserted [B]); the
     cumulative ``pf_inserted`` counter advances by the same amount.
     """
-    (entries, slot_pos, page_table, last_use, pf_flag, ins) = jax.vmap(
-        _warm_insert_one)(state.entries, state.slot_pos, state.page_table,
-                          state.last_use, state.clock, state.pf_flag,
-                          idx, vals, valid)
-    return (BufferState(entries, slot_pos, page_table, last_use,
-                        state.clock, pf_flag, state.pf_inserted + ins,
-                        state.pf_used),
+    (slot_pos, page_table, last_use, pf_flag, ins) = jax.vmap(
+        _warm_insert_one)(state.slot_pos, state.page_table, state.last_use,
+                          state.clock, state.pf_flag, idx, valid)
+    return (BufferState(slot_pos, page_table, last_use, state.clock,
+                        pf_flag, state.pf_inserted + ins, state.pf_used),
             ins)
 
 
 def warm_lane(state: BufferState, lane, idx: jnp.ndarray,
-              vals: jnp.ndarray, valid: jnp.ndarray
-              ) -> Tuple[BufferState, jnp.ndarray]:
+              valid: jnp.ndarray) -> Tuple[BufferState, jnp.ndarray]:
     """Warm-insert into one request lane of a layered buffer.
 
-    state: layered ([L, B, ...]); idx: [L, w]; vals: [L, w, d];
-    valid: [L, w].  The per-layer slices of lane ``lane`` form exactly the
-    batched layout (L plays the batch axis), so this is ``warm_insert``
-    over layers.  Returns (state', total entries inserted) — the prefill
+    state: layered ([L, B, ...]); idx: [L, w]; valid: [L, w].  The
+    per-layer slices of lane ``lane`` form exactly the batched layout (L
+    plays the batch axis), so this is ``warm_insert`` over layers.  Returns (state', total entries inserted) — the prefill
     warm-up path of serving/prefetch.py (radix-reused pages + top-scoring
     prompt entries seeding the hot tier).
     """
     sub = BufferState(*(t[:, lane] for t in state))
-    sub, ins = warm_insert(sub, idx, vals, valid)
+    sub, ins = warm_insert(sub, idx, valid)
     new = BufferState(*(full.at[:, lane].set(part)
                         for full, part in zip(state, sub)))
     return new, ins.sum()
@@ -306,11 +275,10 @@ def warm_lane(state: BufferState, lane, idx: jnp.ndarray,
 
 def init_layered_buffer(n_layers: int, batch: int,
                         buf_size: Union[int, Sequence[int]],
-                        seq_len: int, entry_dim: int,
-                        dtype=jnp.bfloat16,
+                        seq_len: int,
                         buf_max: Union[int, None] = None) -> BufferState:
     """Per-(layer, request) buffer stack: every field gains a leading
-    [L] axis (entries [L, B, buf, d], page_table [L, B, S], ...).
+    [L] axis (slot_pos [L, B, buf], page_table [L, B, S], ...).
 
     ``buf_size`` may be a single size (uniform layers, the PR 1 layout)
     or a per-layer sequence (serving/arbiter.py ``LayerSizer``): the
@@ -322,7 +290,7 @@ def init_layered_buffer(n_layers: int, batch: int,
     needs to grow a layer past its initial share later.
 
     This is the ``hot_buf`` entry of the engine's serve_state pytree;
-    the decode step threads per-layer slices through ``read_through``.
+    the decode step threads per-layer slices through ``swap_in``.
     """
     if isinstance(buf_size, (int, np.integer)):
         sizes = [int(buf_size)] * n_layers
@@ -340,7 +308,6 @@ def init_layered_buffer(n_layers: int, batch: int,
         np.where(np.broadcast_to(slot < sz, (n_layers, batch, buf_max)),
                  int(EMPTY), int(DISABLED)), jnp.int32)
     return BufferState(
-        entries=jnp.zeros((n_layers, batch, buf_max, entry_dim), dtype),
         slot_pos=slot_pos,
         page_table=jnp.full((n_layers, batch, seq_len), EMPTY),
         last_use=jnp.zeros((n_layers, batch, buf_max), jnp.int32),
@@ -351,13 +318,13 @@ def init_layered_buffer(n_layers: int, batch: int,
     )
 
 
-def _resize_one(entries, slot_pos, page_table, last_use, pf_flag, enabled):
+def _resize_one(slot_pos, page_table, last_use, pf_flag, enabled):
     """Single-lane layer re-sizing (vmapped over L*B).
 
     ``enabled``: [buf] bool — the slot belongs to the layer's NEW budget.
     Slots leaving the budget are evicted (their position unmapped from the
     page table) and marked DISABLED; slots entering it open as EMPTY.
-    Slots enabled in both layouts are untouched — resident entries, their
+    Slots enabled in both layouts are untouched — their positions, their
     recency clocks, and their prefetch flags survive the resize, so
     decoded tokens cannot change (the pool stays authoritative either
     way; only *residency* moved).
@@ -371,7 +338,7 @@ def _resize_one(entries, slot_pos, page_table, last_use, pf_flag, enabled):
                          jnp.where(slot_pos == DISABLED, EMPTY, slot_pos))
     last_use = jnp.where(enabled, last_use, 0)
     pf_flag = pf_flag & enabled
-    return entries, slot_pos, page_table, last_use, pf_flag
+    return slot_pos, page_table, last_use, pf_flag
 
 
 def resize_layers(state: BufferState, sizes: Sequence[int]) -> BufferState:
@@ -381,8 +348,8 @@ def resize_layers(state: BufferState, sizes: Sequence[int]) -> BufferState:
     budgets (each <= buf_max — the static allocation width is the hard
     ceiling).  Layer ``l`` keeps its first ``sizes[l]`` slots enabled and
     the rest DISABLED: entries displaced by a shrink are evicted (their
-    next demand read is an honest miss), entries in surviving slots are
-    never corrupted, and the cumulative ``pf_*`` counters are preserved
+    next demand read is an honest miss), surviving slots keep their
+    positions, and the cumulative ``pf_*`` counters are preserved
     (a displaced prefetched entry simply counts as wasted speculation,
     exactly like an LRU eviction would).
 
@@ -403,16 +370,15 @@ def resize_layers(state: BufferState, sizes: Sequence[int]) -> BufferState:
         return t.reshape(L * B, *t.shape[2:])
 
     en = jnp.repeat(enabled, B, axis=0)                    # [L*B, buf]
-    entries, slot_pos, page_table, last_use, pf_flag = jax.vmap(
-        _resize_one)(flat(state.entries), flat(state.slot_pos),
-                     flat(state.page_table), flat(state.last_use),
-                     flat(state.pf_flag), en)
+    slot_pos, page_table, last_use, pf_flag = jax.vmap(_resize_one)(
+        flat(state.slot_pos), flat(state.page_table), flat(state.last_use),
+        flat(state.pf_flag), en)
 
     def unflat(t):
         return t.reshape(L, B, *t.shape[1:])
 
     return BufferState(
-        entries=unflat(entries), slot_pos=unflat(slot_pos),
+        slot_pos=unflat(slot_pos),
         page_table=unflat(page_table), last_use=unflat(last_use),
         clock=state.clock, pf_flag=unflat(pf_flag),
         pf_inserted=state.pf_inserted, pf_used=state.pf_used)
@@ -422,15 +388,13 @@ def reset_lane(state: BufferState, lane: int) -> BufferState:
     """Clear one request lane of a layered buffer ([L, B, ...] layout).
 
     Used when a serving slot is recycled: the next request must not see
-    the previous occupant's residency (its pool pages are reused).
-    Entries need no clearing — unmapped slots are unreachable.  DISABLED
+    the previous occupant's residency (its pool pages are reused).  DISABLED
     slots (per-layer sizing) keep their marker: layer capacities are a
     property of the buffer layout, not of the occupant.
     """
     lane_slots = state.slot_pos[:, lane]
     cleared = jnp.where(lane_slots == DISABLED, DISABLED, EMPTY)
     return BufferState(
-        entries=state.entries,
         slot_pos=state.slot_pos.at[:, lane].set(cleared),
         page_table=state.page_table.at[:, lane].set(EMPTY),
         last_use=state.last_use.at[:, lane].set(0),

@@ -60,10 +60,11 @@ def sparse_attend(p_attn: Dict, p_idx: Dict, x: jnp.ndarray, cfg: ModelConfig,
     set to the trailing window (SWA layers: top-k within the window).
 
     With ``buf_state`` (this layer's HiSparse hot tier, core/hisparse.py)
-    the top-k read goes through ``hisparse.read_through`` — values are
-    bit-identical, but residency is measured so the host can charge only
-    *misses* to the fabric (paper §5.5).  Returns the plain output when
-    ``buf_state`` is None, else ``(out, new_buf_state, hits, misses)``.
+    the top-k read is swapped into the tier (``hisparse.swap_in``) — the
+    values attended are the pool fetch either way, but residency is
+    measured so the host can charge only *misses* to the fabric (paper
+    §5.5).  Returns the plain output when ``buf_state`` is None, else
+    ``(out, new_buf_state, hits, misses)``.
 
     ``prefetch_width`` > 0 (buffered path only) additionally warm-inserts
     the next step's speculated entrants — ``prefetch_fn(scores,
@@ -107,8 +108,8 @@ def sparse_attend(p_attn: Dict, p_idx: Dict, x: jnp.ndarray, cfg: ModelConfig,
         fetched = fetch_fn(kv_pool_l, idx)
     if buf_state is not None:
         with jax.named_scope("hot_tier"):
-            fetched, buf_state, hits, misses = hisparse.read_through(
-                buf_state, idx, fetched, valid)
+            buf_state, hits, misses = hisparse.swap_in(buf_state, idx,
+                                                       valid)
             if speculate:
                 if p_idx_ is None:
                     p_idx_, p_valid = (
@@ -123,10 +124,8 @@ def sparse_attend(p_attn: Dict, p_idx: Dict, x: jnp.ndarray, cfg: ModelConfig,
                     # may issue (lanes are best-first) — traffic shaping
                     # only
                     p_valid = dsa.budget_mask(p_valid, pf_budget)
-                p_vals = fetch_fn(kv_pool_l, jnp.clip(
-                    p_idx_, 0, kv_pool_l.shape[1] - 1))
                 buf_state, _ = hisparse.warm_insert(buf_state, p_idx_,
-                                                    p_vals, p_valid)
+                                                    p_valid)
     with jax.named_scope("attention"):
         fetched = jnp.concatenate(
             [fetched, own_entry[:, None, :].astype(fetched.dtype)], axis=1)

@@ -374,8 +374,9 @@ def _attn_decode(p, x, cfg, ctx, kv_slice, idx_slice, window, hbuf=None):
             positions, own, fetch_fn=ctx["fetch_fn"],
             topk_fn=ctx.get("topk_fn"), window=window)
         return delta, own, new_key, None, None, None
-    # buffered read-through: values are bit-identical, but residency is
-    # measured so the host charges only misses to the fabric (paper §5.5);
+    # buffered: the attended values are the pool fetch as above, and the
+    # hot tier measures residency so the host charges only misses to the
+    # fabric (paper §5.5);
     # prefetch_width > 0 additionally warm-inserts next-step speculation
     # into the hot tier (counted in the buffer's pf_* fields)
     delta, hbuf, hits, misses = sac_core.sparse_attend(
@@ -713,7 +714,7 @@ class TransformerLM:
                 # this segment's hot-buffer layer block, regrouped to
                 # [n, a, ...] so the scan threads one [a, ...] slice per
                 # iteration (mutable xs/ys — unlike the read-only pools,
-                # the buffer is UPDATED by every layer's read_through)
+                # the buffer is UPDATED by every layer's swap_in)
                 with jax.named_scope("hot_tier"):
                     hb_g = jax.tree.map(
                         lambda t: jax.lax.dynamic_slice_in_dim(
@@ -843,12 +844,12 @@ class TransformerLM:
                 state["idx_pool"] = jnp.zeros(
                     (self.n_kv, batch, seq_len, cfg.sac.d_idx), DTYPE)
             if buffered and cfg.sac.enabled and self.mode == "sac":
-                # HiSparse hot tier: per-(layer, request) device buffer;
-                # the decode step reads through it and reports measured
+                # HiSparse hot tier: per-(layer, request) residency; the
+                # decode step swaps its top-k reads in and reports measured
                 # per-request hit/miss counts in buf_hits/buf_misses.
                 state["hot_buf"] = hisparse.init_layered_buffer(
-                    self.n_kv, batch, device_buffer, seq_len, self.kv_dim,
-                    self.kv_dtype, buf_max=buffer_width)
+                    self.n_kv, batch, device_buffer, seq_len,
+                    buf_max=buffer_width)
                 state["buf_hits"] = jnp.zeros((batch,), jnp.int32)
                 state["buf_misses"] = jnp.zeros((batch,), jnp.int32)
                 # per-layer split of the same counters (LayerSizer signal)

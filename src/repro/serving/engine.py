@@ -8,7 +8,7 @@ answers "what would the cluster do", the engine actually *does* it for
 small models — real top-k selection, real pool reads/writes, real radix
 prefix reuse, and the real HiSparse hot buffer (core/hisparse.py) wired
 into the jitted decode step.  With the buffer enabled (default), every
-step's top-k reads go through the in-graph read-through: decoded tokens
+step's top-k reads are swapped into the in-graph hot tier: decoded tokens
 are bit-identical to the buffer-off path, but residency is *measured*,
 and only misses are charged to the fabric (paper §5.5 miss-only
 traffic).  ``EngineStats.buffer_hits/buffer_misses`` are therefore live
@@ -587,14 +587,12 @@ class Engine:
                 + batch * self.profile.per_token_compute_s())
 
     @staticmethod
-    def _warm_apply(hot, kv_pool, lane, idx, valid):
-        """Seed one slot's hot-tier lanes from its pool slice (prefill
-        warm-up): gather the planned positions' entries and warm-insert
-        them (insert-without-read; never evicts current-step hits)."""
-        pool_lane = jnp.take(kv_pool, lane, axis=1)          # [L, S, d]
-        idx = jnp.clip(idx, 0, pool_lane.shape[1] - 1)
-        vals = jax.vmap(lambda p, i: p[i])(pool_lane, idx)   # [L, w, d]
-        return hisparse.warm_lane(hot, lane, idx, vals, valid)
+    def _warm_apply(hot, lane, idx, valid):
+        """Seed one slot's hot-tier lanes (prefill warm-up): make the
+        planned positions resident (insert-without-read; never evicts
+        current-step hits)."""
+        idx = jnp.clip(idx, 0, hot.page_table.shape[-1] - 1)
+        return hisparse.warm_lane(hot, lane, idx, valid)
 
     # -- slot refill -------------------------------------------------------------
     def _locality_bonus_s(self, prompt_len: int, matched: int) -> float:
@@ -816,8 +814,8 @@ class Engine:
                     plan = cap_warmup(plan, w_cap)
                 if plan is not None:
                     hot, n_ins = self._warm(
-                        self.state["hot_buf"], self.state["kv_pool"],
-                        jnp.int32(s), plan.idx, plan.valid)
+                        self.state["hot_buf"], jnp.int32(s), plan.idx,
+                        plan.valid)
                     self.state["hot_buf"] = hot
                     n_ins = int(self._read(n_ins))
                     if n_ins:

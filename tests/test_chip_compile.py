@@ -151,7 +151,7 @@ def test_engine_warmup_compiles(qwen, one_chip):
     cfg, model, _, state = qwen
     width = cfg.sac.warmup_entries + cfg.sac.warmup_radix
     plan = (model.n_kv, width)
-    _compile(Engine._warm_apply, state["hot_buf"], state["kv_pool"],
+    _compile(Engine._warm_apply, state["hot_buf"],
              _sds((), jnp.int32, one_chip), _sds(plan, jnp.int32, one_chip),
              _sds(plan, jnp.bool_, one_chip))
 

@@ -13,17 +13,17 @@ SCOPES = ("indexer", "topk", "gather", "hot_tier", "attention", "pool_slice",
           "pool_write", "mlp", "lm_head", "layers")
 
 
-def _lower():
+def _lower(seq_len: int = 64):
     """The reduced decode, built as ``Engine(..., prefetch=True)`` builds
     it (a fresh model each call, so nothing is reused from a trace made
-    under other scopes)."""
+    under other scopes), over 2 slots of ``seq_len`` positions."""
     cfg = get_config("qwen2-1.5b").reduced()
     sac = cfg.sac
     model = build_model(cfg, mode="sac", opts={
         "prefetch_width": sac.prefetch_width,
         "score_margin": sac.score_margin, "warmup_w": sac.warmup_entries})
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    state = model.serve_state_shapes(2, 64,
+    state = model.serve_state_shapes(2, seq_len,
                                      device_buffer=sac.device_buffer_size)
     tokens = jax.ShapeDtypeStruct((2,), jnp.int32)
     return jax.jit(model.decode).lower(params, state, tokens)
@@ -58,3 +58,24 @@ def test_every_scope_labels_decode_ops_and_changes_no_op(monkeypatch):
     # module without metadata
     assert scoped.as_text() == plain.as_text()
     assert _program(compiled) == _program(plain_compiled)
+
+
+def test_hot_tier_holds_no_entry_values():
+    """The hot tier tracks residency only: in the compiled decode no float
+    op of the ``hot_tier`` scope is an entry wide, and no op anywhere holds
+    a buffer's worth of entries ([..., buf or buf + 1, entry width]).  The
+    96 positions keep the page table's width apart from both."""
+    cfg = get_config("qwen2-1.5b").reduced()
+    d_entry = build_model(cfg, mode="sac").kv_dim
+    buf = cfg.sac.device_buffer_size
+    assert 96 not in (d_entry, buf, buf + 1)
+    text = _lower(seq_len=96).compile().as_text()
+    ops = re.findall(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([0-9,]*)\]([^\n]*)",
+                     text, re.M)
+    assert ops
+    for name, dtype, shape, rest in ops:
+        dims = [int(x) for x in shape.split(",") if x]
+        if not dims or dims[-1] != d_entry:
+            continue
+        assert not (dtype in ("bf16", "f32") and "/hot_tier/" in rest), name
+        assert len(dims) < 2 or dims[-2] not in (buf, buf + 1), (name, dims)

@@ -9,12 +9,17 @@ Acceptance properties (paper §5.5 miss-only traffic):
   - parity: the simulator's analytic hit_rate() matches the
     engine-measured hit rate on a shared drifting-top-k trace.
 """
+import dataclasses
+import json
+from pathlib import Path
 
+import numpy as np
+import pytest
 from parity import assert_parity, drift_parity
 
 from repro.configs import get_config
 from repro.serving.engine import Engine
-from repro.serving.request import sharegpt_trace
+from repro.serving.request import Request, sharegpt_trace
 
 
 def _trace(cfg, n=4, ctx=40, out=6, seed=3):
@@ -100,7 +105,6 @@ def test_per_layer_buffer_sizing_is_transparent():
     non-uniform per-layer sizes summing to the uniform total, decoded
     tokens stay bit-identical, and the per-layer miss counters are live
     so the sizer's miss-rate signal exists."""
-    import dataclasses
     # kv layers: [local (window 8), global] — the window is shrunk below
     # the uniform per-layer size so apportioning has room to act
     cfg = dataclasses.replace(get_config("gemma3-12b").reduced(),
@@ -132,3 +136,68 @@ def test_per_layer_buffer_sizing_is_transparent():
         tot = eng.stats.layer_hits + eng.stats.layer_misses
         assert tot.sum() == eng.stats.buffer_hits + eng.stats.buffer_misses
         assert (eng.stats.layer_miss_rates() >= 0).all()
+
+
+# ---------------------------------------------------------------------------
+# exactness against recorded counters: the hot tier's residency, LRU order,
+# prefetch flags and the tokens, step by step
+# ---------------------------------------------------------------------------
+
+EXACT_DATA = Path(__file__).parent / "data" / "engine_counters.json"
+# case -> (max_ctx, SACConfig changes).  "bench" is the benchmark's CPU
+# rehearsal build (chipbench/run.py --reduced): reduced qwen2-1.5b with
+# top-k 64 over 2 slots x 64, prefetch and radix on.  "resize" keeps the
+# reduced top-k of 16, so speculation lanes reach past the demand set, and
+# re-sizes the layers online every 4 steps.
+EXACT_CASES = {"bench": (64, {"topk": 64}),
+               "resize": (96, {"resize_interval": 4})}
+# (prompt tokens, output tokens) of the rehearsal mix, twice; the fourth
+# prompt repeats the first one's leading 16 tokens (a radix match that
+# seeds the warm-up)
+EXACT_REQUESTS = [(24, 16), (40, 12), (32, 6), (24, 16), (40, 12), (32, 6)]
+
+
+def _exact_run(case: str) -> dict:
+    cfg = get_config("qwen2-1.5b").reduced()
+    max_ctx, sac = EXACT_CASES[case]
+    cfg = dataclasses.replace(cfg, sac=dataclasses.replace(cfg.sac, **sac))
+    eng = Engine(cfg, slots=2, max_ctx=max_ctx, backend="cxl", mode="sac",
+                 prefetch=True, radix=True, seed=5)
+    rng = np.random.default_rng(11)
+    prompts = [rng.integers(0, cfg.vocab, n, dtype=np.int32)
+               for n, _ in EXACT_REQUESTS]
+    prompts[3][:16] = prompts[0][:16]
+    for rid, (p, (_, out)) in enumerate(zip(prompts, EXACT_REQUESTS)):
+        eng.submit(Request(rid, 0.0, len(p), out, p))
+    steps, tokens = [], {}
+    while eng.queue or any(r is not None for r in eng.slot_req):
+        for req in eng.step():
+            tokens[str(req.request_id)] = [int(t) for t in req.out_tokens]
+        st, s = eng.state, eng.stats
+        steps.append({
+            **{k: np.asarray(st[k]).tolist()
+               for k in ("buf_hits", "buf_misses", "buf_hits_l",
+                         "buf_misses_l", "pf_inserted", "pf_useful")},
+            "totals": [s.buffer_hits, s.buffer_misses,
+                       s.prefetched_entries, s.prefetch_useful],
+            "sizes": eng.buffer_sizes and [int(x) for x in eng.buffer_sizes],
+        })
+        assert len(steps) < 200
+    return {"steps": steps, "tokens": tokens}
+
+
+@pytest.mark.parametrize("case", sorted(EXACT_CASES))
+def test_counters_and_tokens_match_recorded(case):
+    """Every step's hits, misses (total and per layer), prefetch inserts
+    and useful prefetches, the LayerSizer's sizes, and every served token
+    equal the recorded run's: the hot tier keeps the same residency and
+    the same LRU victims step by step."""
+    want = json.loads(EXACT_DATA.read_text())[case]
+    got = _exact_run(case)
+    assert got["tokens"] == want["tokens"]
+    assert len(got["steps"]) == len(want["steps"])
+    for i, (g, w) in enumerate(zip(got["steps"], want["steps"])):
+        assert g == w, i
+    # the run exercises what it pins down
+    last = got["steps"][-1]["totals"]
+    assert last[0] > 0 and last[1] > 0 and last[2] > 0 and last[3] > 0

@@ -4,12 +4,12 @@ Invariants (the HiSparse swap-in contract):
   I1. page_table/slot_pos are mutually consistent bijections;
   I2. after swap_in, every (deduped, fillable) requested position is
       resident;
-  I3. read_through values equal pure pool values (the buffer never
-      changes results — only traffic);
+  I3. a hit is exactly a valid lane whose position was resident before
+      the step (the buffer holds positions, never values: reads come from
+      the pool, so it changes traffic, never results);
   I4. hits + misses == number of valid deduped lanes;
   I5. current-step hits are never evicted by the same step's misses.
 """
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -35,39 +35,35 @@ def _consistent(state):
                 assert sp[b, slot] == pos, (b, pos, slot)
 
 
-def _pool(B, S, d, seed=0):
-    return jax.random.normal(jax.random.PRNGKey(seed), (B, S, d),
-                             jnp.bfloat16)
+def _resident_lanes(state, idx, valid):
+    """Per request, the valid lanes of idx resident in ``state``."""
+    _, hit = hisparse.lookup(state, idx)
+    return np.asarray(hit & valid).sum(1)
 
 
 def test_swap_in_basic_residency():
-    B, S, d, buf, k = 2, 32, 8, 8, 4
-    state = hisparse.init_buffer(B, buf, S, d)
-    pool = _pool(B, S, d)
+    B, S, buf, k = 2, 32, 8, 4
+    state = hisparse.init_buffer(B, buf, S)
     idx = jnp.array([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
-    fetched = jnp.take_along_axis(pool, idx[..., None], axis=1)
     valid = jnp.ones((B, k), bool)
-    state, hits, misses = hisparse.swap_in(state, idx, fetched, valid)
+    state, hits, misses = hisparse.swap_in(state, idx, valid)
     assert (np.asarray(hits) == 0).all()
     assert (np.asarray(misses) == k).all()
     _consistent(state)
     slots, hit = hisparse.lookup(state, idx)
     assert bool(hit.all())
     # second time: all hits
-    state, hits, misses = hisparse.swap_in(state, idx, fetched, valid)
+    state, hits, misses = hisparse.swap_in(state, idx, valid)
     assert (np.asarray(hits) == k).all() and (np.asarray(misses) == 0).all()
 
 
 def test_lru_eviction_order():
-    B, S, d, buf = 1, 64, 4, 4
-    state = hisparse.init_buffer(B, buf, S, d)
-    pool = _pool(B, S, d)
+    B, S, buf = 1, 64, 4
+    state = hisparse.init_buffer(B, buf, S)
 
     def touch(state, positions):
         idx = jnp.array([positions], jnp.int32)
-        fetched = jnp.take_along_axis(pool, idx[..., None], axis=1)
-        return hisparse.swap_in(state, idx, fetched,
-                                jnp.ones_like(idx, bool))[0]
+        return hisparse.swap_in(state, idx, jnp.ones_like(idx, bool))[0]
 
     state = touch(state, [0, 1])     # clock 1
     state = touch(state, [2, 3])     # clock 2: buffer full {0,1,2,3}
@@ -80,16 +76,13 @@ def test_lru_eviction_order():
 
 
 def test_protected_hits_not_evicted():
-    B, S, d, buf = 1, 64, 4, 4
-    state = hisparse.init_buffer(B, buf, S, d)
-    pool = _pool(B, S, d)
+    B, S, buf = 1, 64, 4
+    state = hisparse.init_buffer(B, buf, S)
     idx0 = jnp.array([[0, 1, 2, 3]], jnp.int32)
-    f0 = jnp.take_along_axis(pool, idx0[..., None], axis=1)
-    state, _, _ = hisparse.swap_in(state, idx0, f0, jnp.ones_like(idx0, bool))
+    state, _, _ = hisparse.swap_in(state, idx0, jnp.ones_like(idx0, bool))
     # step: 2 hits (0,1 — LRU-oldest) + 2 misses -> must evict 2,3 not 0,1
     idx1 = jnp.array([[0, 1, 20, 21]], jnp.int32)
-    f1 = jnp.take_along_axis(pool, idx1[..., None], axis=1)
-    state, hits, misses = hisparse.swap_in(state, idx1, f1,
+    state, hits, misses = hisparse.swap_in(state, idx1,
                                            jnp.ones_like(idx1, bool))
     assert int(hits[0]) == 2 and int(misses[0]) == 2
     _, hit = hisparse.lookup(state, idx1)
@@ -100,27 +93,23 @@ def test_protected_hits_not_evicted():
 @settings(max_examples=25, deadline=None)
 @given(st.data())
 def test_property_read_through_equals_pool(data):
-    """I3/I4: buffered reads bit-equal pool reads; accounting exact."""
+    """I3/I4: the hits are exactly the valid lanes resident before the
+    step (the lanes a buffer would serve; all lanes read the pool);
+    accounting exact."""
     B = data.draw(st.integers(1, 3))
     S = data.draw(st.sampled_from([16, 32]))
     buf = data.draw(st.sampled_from([4, 8, 16]))
     k = data.draw(st.sampled_from([2, 4, 8]))
-    d = 4
     steps = data.draw(st.integers(1, 5))
-    pool = _pool(B, S, d, seed=data.draw(st.integers(0, 99)))
-    state = hisparse.init_buffer(B, buf, S, d)
+    state = hisparse.init_buffer(B, buf, S)
     rng = np.random.default_rng(data.draw(st.integers(0, 99)))
     for _ in range(steps):
         idx = jnp.asarray(rng.integers(0, S, (B, k)), jnp.int32)
         valid = jnp.asarray(rng.random((B, k)) < 0.9)
-        fetched = jnp.take_along_axis(pool, idx[..., None], axis=1)
-        vals, state, hits, misses = hisparse.read_through(
-            state, idx, fetched, valid)
-        # values identical to the pool for valid lanes
-        expect = jnp.take_along_axis(pool, idx[..., None], axis=1)
+        resident = _resident_lanes(state, idx, valid)
+        state, hits, misses = hisparse.swap_in(state, idx, valid)
+        np.testing.assert_array_equal(np.asarray(hits), resident)
         v = np.asarray(valid)
-        np.testing.assert_array_equal(
-            np.asarray(vals, np.float32)[v], np.asarray(expect, np.float32)[v])
         _consistent(state)
         # I4: hits+misses == valid deduped lanes
         for b in range(B):
@@ -141,10 +130,9 @@ def test_hit_rate_grounding():
     """The simulator's hit model must be in the ballpark of the real
     buffer under a drifting top-k workload (grounds serving/simulator)."""
     from repro.serving.simulator import hit_rate as model_hit
-    B, S, d = 1, 2048, 4
+    B, S = 1, 2048
     k, buf = 64, 192  # k/buf = 1/3 like 2048/6144
-    state = hisparse.init_buffer(B, buf, S, d)
-    pool = _pool(B, S, d)
+    state = hisparse.init_buffer(B, buf, S)
     rng = np.random.default_rng(0)
     # drifting top-k: mostly same set, a few swaps per step
     current = rng.choice(S, size=k, replace=False)
@@ -155,9 +143,7 @@ def test_hit_rate_grounding():
         newpos = rng.integers(0, S, n_swap)
         current[drop] = newpos
         idx = jnp.asarray(current[None, :], jnp.int32)
-        fetched = jnp.take_along_axis(pool, idx[..., None], axis=1)
-        _, state, h, m = hisparse.read_through(
-            state, idx, fetched, jnp.ones((1, k), bool))
+        state, h, m = hisparse.swap_in(state, idx, jnp.ones((1, k), bool))
         if step >= 10:  # skip warmup
             hits += int(h[0]); misses += int(m[0])
     real = hits / (hits + misses)
@@ -177,10 +163,9 @@ def _layered_consistent(state):
 
 
 def test_resize_layers_grow_shrink_preserves_residents():
-    st = hisparse.init_layered_buffer(2, 1, [4, 2], 16, 3, buf_max=6)
+    st = hisparse.init_layered_buffer(2, 1, [4, 2], 16, buf_max=6)
     idx = jnp.array([[0, 1, 2], [3, 4, 5]], jnp.int32)
-    vals = jnp.ones((2, 3, 3), jnp.bfloat16)
-    st, ins = hisparse.warm_lane(st, 0, idx, vals, jnp.ones((2, 3), bool))
+    st, ins = hisparse.warm_lane(st, 0, idx, jnp.ones((2, 3), bool))
     assert int(ins) == 5                       # layer 1 capped at 2 slots
     st2 = hisparse.resize_layers(st, [2, 5])
     _layered_consistent(st2)
@@ -193,18 +178,24 @@ def test_resize_layers_grow_shrink_preserves_residents():
     # layer 1 grew: residents kept, new slots open EMPTY
     assert sp[1].tolist() == [3, 4, -1, -1, -1, -2]
     assert pt[1][3] == 0 and pt[1][4] == 1
-    # entries in surviving slots are untouched
-    np.testing.assert_array_equal(
-        np.asarray(st2.entries[:, 0, :2], np.float32),
-        np.asarray(st.entries[:, 0, :2], np.float32))
+    # surviving slots are untouched: their positions, the page table's
+    # mapping of those positions, their clocks and prefetch flags
+    old_sp = np.asarray(st.slot_pos)[:, 0, :2]
+    np.testing.assert_array_equal(sp[:, :2], old_sp)
+    for layer in range(2):
+        for slot, pos in enumerate(old_sp[layer]):
+            assert pt[layer][pos] == slot
+    for f in ("last_use", "pf_flag"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(st2, f))[:, 0, :2],
+            np.asarray(getattr(st, f))[:, 0, :2])
 
 
 def test_resize_layers_roundtrip_restores_capacity_not_residency():
-    st = hisparse.init_layered_buffer(1, 2, [4], 8, 2)
+    st = hisparse.init_layered_buffer(1, 2, [4], 8)
     idx = jnp.array([[0, 1, 2, 3], [4, 5, 6, 7]], jnp.int32)
-    vals = jnp.ones((2, 4, 2), jnp.bfloat16)
     st, _, _ = hisparse.swap_in(
-        hisparse.BufferState(*(t[0] for t in st)), idx, vals,
+        hisparse.BufferState(*(t[0] for t in st)), idx,
         jnp.ones((2, 4), bool))
     st = hisparse.BufferState(*(t[None] for t in st))
     shrunk = hisparse.resize_layers(st, [1])
@@ -217,20 +208,20 @@ def test_resize_layers_roundtrip_restores_capacity_not_residency():
 
 
 def test_resize_layers_read_through_stays_bit_identical():
-    """After an arbitrary resize, demand reads still return pool values
-    exactly — displaced entries just miss (traffic, not tokens)."""
-    B, S, d = 2, 12, 4
-    st = hisparse.init_layered_buffer(1, B, [6], S, d)
-    pool = _pool(B, S, d)
+    """After an arbitrary resize, demand reads still count exactly the
+    lanes still resident as hits — displaced entries just miss (traffic,
+    not tokens: every lane reads the pool)."""
+    B, S = 2, 12
+    st = hisparse.init_layered_buffer(1, B, [6], S)
     rng = np.random.default_rng(3)
     flat = hisparse.BufferState(*(t[0] for t in st))
     for step in range(8):
         idx = jnp.asarray(rng.integers(0, S, (B, 4)), jnp.int32)
-        fetched = jax.vmap(lambda p, i: p[i])(pool, idx)
-        vals, flat, _, _ = hisparse.read_through(
-            flat, idx, fetched, jnp.ones((B, 4), bool))
-        np.testing.assert_array_equal(np.asarray(vals, np.float32),
-                                      np.asarray(fetched, np.float32))
+        valid = jnp.ones((B, 4), bool)
+        resident = _resident_lanes(flat, idx, valid)
+        flat, hits, _ = hisparse.swap_in(flat, idx, valid)
+        np.testing.assert_array_equal(np.asarray(hits), resident)
+        _consistent(flat)
         if step == 3:
             layered = hisparse.BufferState(*(t[None] for t in flat))
             layered = hisparse.resize_layers(layered, [3])
@@ -239,10 +230,10 @@ def test_resize_layers_read_through_stays_bit_identical():
 
 
 def test_init_layered_buffer_buf_max_headroom():
-    st = hisparse.init_layered_buffer(2, 1, [4, 2], 8, 3, buf_max=7)
-    assert st.entries.shape[2] == 7
+    st = hisparse.init_layered_buffer(2, 1, [4, 2], 8, buf_max=7)
+    assert st.slot_pos.shape[2] == 7
     sp = np.asarray(st.slot_pos)[:, 0]
     assert (sp[0] == -1).sum() == 4 and (sp[0] == -2).sum() == 3
     assert (sp[1] == -1).sum() == 2 and (sp[1] == -2).sum() == 5
     with pytest.raises(AssertionError):
-        hisparse.init_layered_buffer(1, 1, [4], 8, 3, buf_max=2)
+        hisparse.init_layered_buffer(1, 1, [4], 8, buf_max=2)
