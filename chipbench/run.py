@@ -56,7 +56,7 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from chipbench import stats  # noqa: E402
+from chipbench import scopes, stats  # noqa: E402
 from chipbench.generator import Traffic  # noqa: E402
 from chipbench.trace import WINDOW, Trace, find_xplane  # noqa: E402
 
@@ -104,31 +104,64 @@ def load_cell(name: str, reduced: bool) -> SimpleNamespace:
                            conf=conf, mix=mix)
 
 
+# what the program runs for a name of a file's ``model`` block that is no
+# field of its configuration (or a field it leaves None and derives)
+PROGRAM_VALUES = {
+    # ModelConfig.head_dim is None where the head is d_model // n_heads;
+    # every layer uses ModelConfig.hd (src/repro/configs/base.py, ``def hd``)
+    "head_dim": lambda cfg: cfg.hd,
+    # every norm is rms_norm's default (src/repro/models/layers.py,
+    # ``def rms_norm(x, gamma, eps=1e-6)``)
+    "rms_norm_eps": lambda cfg: 1e-6,
+    # the program always holds a head of its own (src/repro/models/
+    # transformer.py, ``model_param_specs``: ``"lm_head": ParamSpec(...)``)
+    "tie_word_embeddings": lambda cfg: False,
+}
+
+
+def program_value(cfg, key: str):
+    """The value the program runs for ``key``: a field of the model's
+    configuration, else of its SAC configuration, else
+    :data:`PROGRAM_VALUES`; KeyError where the program has it nowhere."""
+    fields = {f.name for f in dataclasses.fields(cfg)}
+    if key in fields and (getattr(cfg, key) is not None
+                          or key not in PROGRAM_VALUES):
+        return getattr(cfg, key)
+    if key in {f.name for f in dataclasses.fields(cfg.sac)}:
+        return getattr(cfg.sac, key)
+    if key in PROGRAM_VALUES:
+        return PROGRAM_VALUES[key](cfg)
+    raise KeyError(key)
+
+
 def model_config(conf: dict, reduced: Optional[dict]):
     """The registry configuration with the file's overrides, checked
-    against the sizes the file states; returns ``(cfg, sizes)``.  With
-    ``reduced`` (the cell's rehearsal block), the registry's tiny
-    configuration, its top-k set to the block's."""
+    against every size the file's ``model`` block states; returns ``(cfg,
+    sizes)``, ``sizes`` holding the block's keys.  With ``reduced`` (the
+    cell's rehearsal block), the registry's tiny configuration, its top-k
+    set to the block's, and ``sizes`` holds its values."""
     from repro.configs import get_config
     cfg = dataclasses.replace(get_config(conf["arch"]), **conf["overrides"])
     if reduced:
         cfg = cfg.reduced()
         cfg = dataclasses.replace(
             cfg, sac=dataclasses.replace(cfg.sac, topk=reduced["topk"]))
-    sizes = {"n_layers": cfg.n_layers, "d_model": cfg.d_model,
-             "n_heads": cfg.n_heads, "n_kv_heads": cfg.n_kv_heads,
-             "head_dim": cfg.hd, "d_ff": cfg.d_ff, "vocab": cfg.vocab,
-             "qkv_bias": cfg.qkv_bias, "rope_theta": cfg.rope_theta,
-             "rms_norm_eps": 1e-6, "tie_word_embeddings": False,
-             "topk": cfg.sac.topk, "d_idx": cfg.sac.d_idx,
-             "n_idx_heads": cfg.sac.n_idx_heads}
-    if not reduced and sizes != conf["model"]:
-        diff = {k: (sizes.get(k), conf["model"].get(k))
-                for k in set(sizes) | set(conf["model"])
-                if sizes.get(k) != conf["model"].get(k)}
+    stated = conf["model"]
+    sizes, unknown = {}, []
+    for k in stated:
+        try:
+            sizes[k] = program_value(cfg, k)
+        except KeyError:
+            unknown.append(k)
+    if unknown:
+        raise SystemExit(f"{conf['name']}: the program has no {unknown} "
+                         "(model block of the file)")
+    if not reduced and sizes != stated:
+        diff = {k: (sizes[k], stated[k]) for k in stated
+                if sizes[k] != stated[k]}
         raise SystemExit(f"{conf['name']}: the program's configuration "
                          f"differs from the file (program, file): {diff}")
-    return cfg, (sizes if reduced else conf["model"])
+    return cfg, (sizes if reduced else stated)
 
 
 def device_of(chips: int, reduced: bool, peaks: dict) -> dict:
@@ -222,8 +255,9 @@ def wrap(obj, attr: str, span: str, before=None):
 
 def instrument(eng, win) -> str:
     """Wrap the engine's serving methods in host spans, note the prompt
-    tokens of every prefill, and start the profiler; returns the trace
-    directory."""
+    tokens of every prefill and the arguments of the last decode after the
+    state, and start the profiler; returns the trace directory."""
+    win.decode = eng._decode
     wrap(eng, "_fill_slots", "Engine._fill_slots")
     wrap(eng, "_admit_request", "Engine._admit_request")
     wrap(eng, "_prefill_one", "Engine._prefill_one",
@@ -231,12 +265,31 @@ def instrument(eng, win) -> str:
              (time.monotonic(), int(toks.shape[1]))))
     wrap(eng, "_splice_state", "Engine._splice_state")
     wrap(eng, "_warm", "Engine._warm")
-    wrap(eng, "_decode", "Engine._decode")
+    wrap(eng, "_decode", "Engine._decode",
+         before=lambda params, state, *rest: setattr(win, "decode_rest",
+                                                     rest))
     trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
     return trace_dir
+
+
+def decode_text(eng, win, log: CompileLog) -> dict:
+    """The text of the compiled decode the traced window ran: the engine's
+    jitted decode lowered on its parameters, its state (the last decode's
+    output, so of the shapes every decode takes) and the last decode's
+    other arguments; the executable comes from JAX's caches, and
+    ``compiles`` counts a fresh compile (another program) if one was made.
+    Empty where the window made no decode through a jitted function."""
+    if not hasattr(win.decode, "lower") or win.decode_rest is None:
+        return {"text": "", "compiles": 0, "seconds": 0.0}
+    t = time.monotonic()
+    log.take()
+    text = win.decode.lower(eng.params, eng.state,
+                            *win.decode_rest).compile().as_text()
+    return {"text": text, "compiles": log.take()["compiles"],
+            "seconds": time.monotonic() - t}
 
 
 def build_engine(cfg, cell: dict, seed: int):
@@ -286,6 +339,8 @@ class Window:
         self.finished_tokens = {}         # rid -> served tokens
         self.client_of = {}               # rid -> client
         self.sent = collections.Counter()  # client -> requests sent
+        self.decode = None                # the jitted decode (traced runs)
+        self.decode_rest = None           # its last call's args after state
 
     def send(self, c: int):
         item = self.traffic.request(c, self.sent[c])
@@ -470,21 +525,30 @@ def main(argv=None) -> int:
     in_window_gc = gc_log.take()
     dev = jax.devices()[0]
     mem = (dev.memory_stats() or {}).get("peak_bytes_in_use")
-    trace = None
+    trace = scope_ms = None
     if args.trace:
         t_stop = time.monotonic()
         jax.profiler.stop_trace()
         t_read = time.monotonic()
         trace = Trace(find_xplane(trace_dir[0]))
         shutil.rmtree(trace_dir[0], ignore_errors=True)
+        stop_s, read_s = t_read - t_stop, time.monotonic() - t_read
+        names = spec.conf.get("scopes", [])
+        text = decode_text(eng, win, log)
+        op_scope = scopes.op_scopes(text["text"], names)
+        scope_ms, unmapped = scopes.scope_ms(trace, "jit_decode", op_scope,
+                                             names)
         # a trace that lost events holds fewer decode executions than the
         # host made steps in the traced part
-        emit(phase="trace", stop_s=t_read - t_stop,
-             read_s=time.monotonic() - t_read, op_events=len(trace.ops),
+        emit(phase="trace", stop_s=stop_s, read_s=read_s,
+             op_events=len(trace.ops),
              decode_executions=len(trace.executions("jit_decode")),
              steps_traced=sum(1 for t, _ in win.steps if tt < t <= t1),
              busy_s=trace.busy_s(), op_busy_s=trace.busy_s(ops=True),
-             window_s=trace.window_s())
+             window_s=trace.window_s(), decode_ms_by_scope=scope_ms,
+             ops_unmapped=unmapped, decode_text_s=text["seconds"],
+             decode_text_compiles=text["compiles"],
+             decode_text_ops=collections.Counter(op_scope.values()))
     records = list(win.records.values())
     emit(phase="setup", setup_s=t_setup - T_PROCESS, **setup)
     emit(phase="window", seconds=t1 - t0,
@@ -533,7 +597,7 @@ def main(argv=None) -> int:
         if args.trace:
             run = SimpleNamespace(
                 records=records, t0=tt, t1=t1, trace=trace, peaks=peak,
-                prefills=win.prefills,
+                prefills=win.prefills, scope_ms=scope_ms,
                 step_work=_step_work(spec, sizes, win, tt, t1))
             metrics = {}
             for m in metric_entries(spec, "per_layer"):

@@ -39,6 +39,8 @@ def test_cell_resolves_its_files(cell):
     entry = {c["name"]: c for c in BENCH["configs"]}[cell["config"]]
     assert entry["file"] == f"chipbench/configs/{cell['config']}.json"
     assert entry["reduced"] == conf["reduced"]
+    # the decode's named scopes, which the traced run splits its time by
+    assert conf["scopes"] and len(set(conf["scopes"])) == len(conf["scopes"])
     mix = json.loads((ROOT / "chipbench/traffic"
                       / f"{cell['traffic']}.json").read_text())
     assert spec["clients"] >= 1 and mix["round"] >= 1

@@ -5,6 +5,8 @@ like ``small.xplane.pb``) with the text of the decode program that run
 compiled."""
 import bisect
 import gzip
+import importlib
+import json
 from pathlib import Path
 from types import SimpleNamespace
 
@@ -14,6 +16,13 @@ from chipbench import scopes, trace
 from chipbench.metrics import host_step_ms
 
 DATA = Path(__file__).resolve().parent / "data"
+# the scopes of the program that recorded the trace, as its configuration
+# file lists them
+NAMES = json.loads((Path(__file__).resolve().parents[1] / "configs"
+                    / "qwen2-1.5b.json").read_text())["scopes"]
+SCOPE_READERS = {"decode_hot_tier_ms": "hot_tier", "decode_topk_ms": "topk",
+                 "decode_indexer_ms": "indexer",
+                 "decode_pool_write_ms": "pool_write"}
 
 HLO = """HloModule jit_f, is_scheduled=true
 
@@ -29,22 +38,35 @@ ENTRY %main.1 (x.1: f32[4]) -> f32[4] {
   %fusion.1 = f32[4]{0} fusion(%copy.1), kind=kLoop, calls=%fused_computation
   %copy.2 = f32[4]{0} copy(%fusion.1)
   %cosine.1 = f32[4]{0} cosine(%copy.2), metadata={op_name="jit(f)/gather"}
-  ROOT %negate.1 = f32[4]{0} negate(%cosine.1), metadata={op_name="jit(f)/gather/gather/neg"}
+  %negate.1 = f32[4]{0} negate(%cosine.1), metadata={op_name="jit(f)/gather/gather/neg"}
+  ROOT %exp.1 = f32[4]{0} exponential(%negate.1), metadata={op_name="jit(f)/moe/experts/exp"}
 }
 """
 
 
 def test_scope_of_is_the_innermost_listed_scope_before_the_primitive():
     assert scopes.scope_of("jit(decode)/while/body/closed_call/hot_tier/"
-                           "jit(take_along_axis)/gather") == "hot_tier"
+                           "jit(take_along_axis)/gather", NAMES) == "hot_tier"
     assert scopes.scope_of("jit(decode)/layers/while/body/closed_call/"
-                           "topk/sort") == "topk"
-    assert scopes.scope_of("jit(decode)/gather") == scopes.UNSCOPED
-    assert scopes.scope_of("") == scopes.UNSCOPED
+                           "topk/sort", NAMES) == "topk"
+    assert scopes.scope_of("jit(decode)/gather", NAMES) == scopes.UNSCOPED
+    assert scopes.scope_of("", NAMES) == scopes.UNSCOPED
+
+
+def test_scopes_come_from_the_argument():
+    """A name is a scope only where the list given names it: ``moe`` and
+    ``experts`` are no scopes of the qwen2 program's list."""
+    path = "jit(decode)/layers/moe/experts/dot_general"
+    assert scopes.scope_of(path, NAMES) == "layers"
+    assert scopes.scope_of(path, ["moe"]) == "moe"
+    assert scopes.scope_of(path, ["moe", "experts"]) == "experts"
+    assert scopes.op_scopes(HLO, NAMES)["%exp.1"] == scopes.UNSCOPED
+    assert scopes.op_scopes(HLO, [*NAMES, "moe"])["%exp.1"] == "moe"
+    assert scopes.op_scopes(HLO, ["moe"])["%sine.1"] == scopes.UNSCOPED
 
 
 def test_op_scopes_from_paths_fusions_and_users():
-    got = scopes.op_scopes(HLO)
+    got = scopes.op_scopes(HLO, NAMES)
     assert got["%sine.1"] == "indexer"
     assert got["%fusion.1"] == "mlp"          # its fused computation's root
     assert got["%copy.1"] == "mlp"            # no path: the scope of its user
@@ -62,7 +84,7 @@ def scoped():
 @pytest.fixture(scope="module")
 def op_scope():
     with gzip.open(DATA / "scoped_decode.hlo.gz", "rt") as f:
-        return scopes.op_scopes(f.read())
+        return scopes.op_scopes(f.read(), NAMES)
 
 
 def _decode_ops(tr):
@@ -75,8 +97,8 @@ def _decode_ops(tr):
 def test_scopes_add_up_to_the_decode_op_time(scoped, op_scope):
     ops = _decode_ops(scoped)
     assert ops and all(op in op_scope for op, _, _, _ in ops)
-    by = scopes.scope_s(scoped, "jit_decode", op_scope)
-    assert set(by) == set(scopes.SCOPES) | {scopes.UNSCOPED}
+    by = scopes.scope_s(scoped, "jit_decode", op_scope, NAMES)
+    assert set(by) == set(NAMES) | {scopes.UNSCOPED}
     assert sum(by.values()) == pytest.approx(sum(o for *_, o in ops))
     # self times tile the time in which a decode op ran
     busy = sum(e - s for s, e in trace.merge([(s, e) for _, s, e, _ in ops]))
@@ -85,6 +107,37 @@ def test_scopes_add_up_to_the_decode_op_time(scoped, op_scope):
                  "pool_write", "mlp", "lm_head", "layers"):
         assert by[part] > 0, part
     assert by[scopes.UNSCOPED] <= 0.1 * sum(by.values())
+
+
+def test_scope_ms_is_the_decode_op_time_per_execution(scoped, op_scope):
+    by, unmapped = scopes.scope_ms(scoped, "jit_decode", op_scope, NAMES)
+    assert unmapped == 0
+    assert set(by) == set(NAMES) | {scopes.UNSCOPED}
+    ops = _decode_ops(scoped)
+    busy = sum(e - s for s, e in trace.merge([(s, e) for _, s, e, _ in ops]))
+    n = len(scoped.executions("jit_decode"))
+    assert sum(by.values()) == pytest.approx(busy / 1e6 / n, rel=1e-3)
+    assert all(v > 0 for k, v in by.items() if k != "pool_slice")
+
+
+def test_ops_unmapped_counts_ops_absent_from_the_text(scoped, op_scope):
+    """An op the text does not name (the text is of another program)
+    is counted, and the split is then None."""
+    ops = _decode_ops(scoped)
+    gone = max(ops, key=lambda o: o[3])[0]
+    partial = {k: v for k, v in op_scope.items() if k != gone}
+    by, unmapped = scopes.scope_ms(scoped, "jit_decode", partial, NAMES)
+    assert by is None
+    assert unmapped == sum(op == gone for op, *_ in ops) > 0
+
+
+@pytest.mark.parametrize("name", sorted(SCOPE_READERS))
+def test_scope_readers_read_their_scope(name):
+    reader = importlib.import_module(f"chipbench.metrics.{name}")
+    assert reader.read(SimpleNamespace(scope_ms=None)) is None
+    split = {s: float(i) + 0.5 for i, s in enumerate(NAMES)}
+    assert reader.read(SimpleNamespace(scope_ms=split)) == split[
+        SCOPE_READERS[name]]
 
 
 def _spans(tr, name):
@@ -123,3 +176,36 @@ def test_host_step_ms_reads_the_program_spans(scoped):
     # none
     old = trace.Trace(str(DATA / "small.xplane.pb"))
     assert host_step_ms.read(SimpleNamespace(trace=old)) is None
+
+
+def test_decode_text_is_of_the_executable_that_ran():
+    """The harness takes the decode's text from the jitted function the
+    window called, on the engine's state and the last call's other
+    arguments: JAX's caches hand back the executable that ran, with no
+    compile of its own, and its scopes are the program's."""
+    import jax
+    import jax.numpy as jnp
+    run = importlib.import_module("chipbench.run")
+
+    def decode(params, state, tokens):
+        with jax.named_scope("topk"):
+            new = jnp.sort(state + params * tokens[:, None], axis=-1)
+        with jax.named_scope("mlp"):
+            return new, jnp.tanh(new).sum(-1)
+
+    jitted = jax.jit(decode)
+    eng = SimpleNamespace(params=jnp.float32(2.0),
+                          state=jnp.arange(12.0).reshape(3, 4))
+    win = SimpleNamespace(decode=jitted, decode_rest=None)
+    log = run.CompileLog()
+    assert run.decode_text(eng, win, log)["text"] == ""
+    tokens = jnp.array([1.0, 2.0, 3.0])
+    for _ in range(2):
+        win.decode_rest = (tokens,)
+        eng.state, _ = jitted(eng.params, eng.state, tokens)
+    got = run.decode_text(eng, win, log)
+    assert got["compiles"] == 0
+    assert got["text"] == jitted.lower(eng.params, eng.state,
+                                       tokens).compile().as_text()
+    assert {"topk", "mlp"} <= set(scopes.op_scopes(got["text"],
+                                                   ["topk", "mlp"]).values())
