@@ -184,8 +184,25 @@ class ModelConfig:
     rope_theta: float = 1e6
 
     # --- MoE ---
-    n_experts: int = 0
+    n_experts: int = 0               # routed experts: the router's width
     topk_experts: int = 0
+    first_k_dense: int = 0           # leading dense layers before the
+                                     # expert layers (first_k_dense_replace)
+    d_ff_dense: int = 0              # their MLP width (0: d_ff, which is
+                                     # then the expert width)
+    n_shared_experts: int = 0        # experts every token passes through
+    n_experts_held: int = 0          # routed experts this chip holds (0:
+                                     # all of them); the noaux_tc layer
+                                     # computes only their part
+    expert_offset: int = 0           # the first of them
+    router: str = "softmax"          # softmax: capacity top-k (GShard);
+                                     # noaux_tc: DeepSeek's sigmoid scores,
+                                     # group-limited top-k, dropless
+    n_expert_groups: int = 1         # noaux_tc expert groups (n_group)
+    n_limited_groups: int = 1        # groups a token may route into
+                                     # (topk_group)
+    norm_topk_prob: bool = True      # renormalise the picked weights
+    routed_scaling_factor: float = 1.0
 
     # --- sliding window / local:global attention ---
     sliding_window: int = 0          # 0 = full attention (mixtral: 4096)
@@ -206,6 +223,15 @@ class ModelConfig:
     kv_lora_rank: int = 512          # latent KV dim
     qk_rope_dim: int = 64
     q_lora_rank: int = 1536
+    idx_rope_dim: int = 0            # rope dims of the V3.2 indexer heads
+
+    # --- YaRN (rope_scaling type "yarn"; factor 1 = plain RoPE) ---
+    rope_factor: float = 1.0
+    rope_original_max_pos: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     # --- early fusion VLM (chameleon) ---
     vlm: bool = False
@@ -216,6 +242,10 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim if self.head_dim else self.d_model // self.n_heads
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     @property
     def has_attention(self) -> bool:
@@ -284,6 +314,8 @@ class ModelConfig:
             n_layers = 2                      # one (1 local + 1 global) super
         elif self.shared_attn_every:
             n_layers = 5                      # 2 supers of 2 + 1 tail
+        elif self.first_k_dense:
+            n_layers = 3                      # 1 leading dense + 2 expert
         else:
             n_layers = min(self.n_layers, 2)
         return dataclasses.replace(
@@ -298,6 +330,15 @@ class ModelConfig:
             vocab=256,
             n_experts=min(self.n_experts, 4),
             topk_experts=min(self.topk_experts, 2),
+            first_k_dense=min(self.first_k_dense, 1),
+            d_ff_dense=256 if self.d_ff_dense else 0,
+            # a held share that straddles the router's groups: experts 1
+            # and 2 of 4, in groups {0, 1} and {2, 3}
+            n_experts_held=2 if self.router == "noaux_tc" else 0,
+            expert_offset=1 if self.router == "noaux_tc" else 0,
+            n_expert_groups=min(self.n_expert_groups, 2),
+            n_limited_groups=min(self.n_limited_groups, 1),
+            idx_rope_dim=4 if self.idx_rope_dim else 0,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             shared_attn_every=2 if self.shared_attn_every else 0,
             local_global_ratio=1 if self.local_global_ratio else 0,

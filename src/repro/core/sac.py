@@ -84,7 +84,8 @@ def sparse_attend(p_attn: Dict, p_idx: Dict, x: jnp.ndarray, cfg: ModelConfig,
     # the named scopes label the decode program's ops by part, for the
     # device trace; they change no op
     with jax.named_scope("indexer"):
-        scores = dsa.indexer_scores(p_idx, x, idx_pool_l, cfg)
+        scores = dsa.indexer_scores(p_idx, x, idx_pool_l, cfg,
+                                    p_attn=p_attn, positions=positions)
         if window:
             # candidate set = (cache_len - window, cache_len]: size-`window`
             # trailing window including the (appended) current token.

@@ -5,7 +5,10 @@ This implements the model-side machinery the SAC paper serves:
   - **Lightning indexer** (paper Fig 1): low-dim projected keys stored per
     token; at decode time the current query scores *all* cached positions
     ``I[t,s] = sum_h w[t,h] * ReLU(q_idx[t,h] . k_idx[s])`` and the top-k
-    positions are selected.
+    positions are selected.  MLA configurations run DeepSeek-V3.2's
+    indexer (its ``inference/model.py``): the query from the query latent,
+    a LayerNorm on the key, RoPE on the first ``idx_rope_dim`` dims of
+    both, and head weights ``weights_proj(x) / sqrt(n_heads)``.
   - **MLA** (multi-head latent attention): prefill runs the non-absorbed
     form and emits the latent cache entry ``(c_kv, k_rope)`` = 512+64 dims;
     decode runs the *absorbed* form directly over fetched latent entries.
@@ -25,7 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.layers import ParamSpec, apply_rope, rms_norm
+from repro.models.layers import (ParamSpec, apply_rope, rms_norm,
+                                 yarn_freqs, yarn_mscale)
 
 NEG_INF = -1e30
 
@@ -37,6 +41,14 @@ NEG_INF = -1e30
 
 def indexer_param_specs(cfg) -> Dict[str, ParamSpec]:
     d, ni, di = cfg.d_model, cfg.sac.n_idx_heads, cfg.sac.d_idx
+    if cfg.mla:
+        return {
+            "wq_b": ParamSpec((cfg.q_lora_rank, ni * di), ("C", "H")),
+            "wk": ParamSpec((d, di), ("D", "C")),
+            "k_norm_g": ParamSpec((di,), ("C",), init="ones"),
+            "k_norm_b": ParamSpec((di,), ("C",), init="zeros"),
+            "weights_proj": ParamSpec((d, ni), ("D", "C")),
+        }
     return {
         "wq_idx": ParamSpec((d, ni * di), ("D", "H")),
         "wk_idx": ParamSpec((d, di), ("D", "C")),
@@ -44,19 +56,51 @@ def indexer_param_specs(cfg) -> Dict[str, ParamSpec]:
     }
 
 
-def indexer_keys(p, x) -> jnp.ndarray:
-    """Per-token indexer keys. x: [..., D] -> [..., d_idx]."""
+def _layer_norm(x, g, b, eps=1e-6):
+    x32 = x.astype(jnp.float32)
+    mu = x32.mean(-1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mu), -1, keepdims=True)
+    y = (x32 - mu) * jax.lax.rsqrt(var + eps)
+    return (y * g.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
+
+
+def _idx_rope(x, positions, cfg):
+    """RoPE on the first ``idx_rope_dim`` dims of V3.2 indexer vectors."""
+    r = cfg.idx_rope_dim
+    if not r:
+        return x
+    pe = apply_rope(x[..., :r], positions, cfg.rope_theta,
+                    freqs=rope_freqs_of(cfg, r))
+    return jnp.concatenate([pe, x[..., r:]], axis=-1)
+
+
+def indexer_keys(p, x, cfg=None, positions=None) -> jnp.ndarray:
+    """Per-token indexer keys. x: [..., D] -> [..., d_idx]; ``positions``
+    ([...]) place the V3.2 keys of MLA configurations."""
+    if cfg is not None and cfg.mla:
+        k = _layer_norm(x @ p["wk"], p["k_norm_g"], p["k_norm_b"])
+        return _idx_rope(k, positions, cfg)
     return x @ p["wk_idx"]
 
 
-def indexer_scores(p, xq, idx_keys, cfg) -> jnp.ndarray:
+def indexer_scores(p, xq, idx_keys, cfg, p_attn=None, positions=None
+                   ) -> jnp.ndarray:
     """Score all cached positions against the current query token.
 
     xq: [B, D] (query-token activations); idx_keys: [B, S, d_idx]
-    -> scores [B, S] (f32).
+    -> scores [B, S] (f32).  MLA configurations take the query from the
+    attention's query latent (``p_attn``) at ``positions`` ([B]).
     """
     B = xq.shape[0]
     ni, di = cfg.sac.n_idx_heads, cfg.sac.d_idx
+    if cfg.mla:
+        q = (mla_q_latent(p_attn, xq) @ p["wq_b"]).reshape(B, ni, di)
+        q = _idx_rope(q, positions[:, None], cfg).astype(jnp.float32)
+        w = (xq @ p["weights_proj"]).astype(jnp.float32) / np.sqrt(ni)
+        logits = jnp.einsum("bhd,bsd->bhs", q,
+                            idx_keys.astype(jnp.float32))
+        logits = jax.nn.relu(logits) / np.sqrt(di)
+        return jnp.einsum("bh,bhs->bs", w, logits)
     q = (xq @ p["wq_idx"]).reshape(B, ni, di).astype(jnp.float32)
     w = (xq @ p["w_w"]).astype(jnp.float32)                      # [B, ni]
     logits = jnp.einsum("bhd,bsd->bhs", q, idx_keys.astype(jnp.float32))
@@ -206,14 +250,49 @@ def mla_param_specs(cfg) -> Dict[str, ParamSpec]:
     }
 
 
+def rope_freqs_of(cfg, dim: int):
+    """RoPE frequencies of a ``dim``-wide MLA or indexer rope part: YaRN's
+    where the configuration scales its context, else None (plain)."""
+    if cfg.rope_factor <= 1:
+        return None
+    return jnp.asarray(yarn_freqs(dim, cfg.rope_theta, cfg.rope_factor,
+                                  cfg.rope_original_max_pos,
+                                  cfg.rope_beta_fast, cfg.rope_beta_slow))
+
+
+def mla_softmax_scale(cfg) -> float:
+    """1/sqrt(qk head dim), times YaRN's mscale squared."""
+    m = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+         if cfg.rope_mscale_all_dim else 1.0)
+    return m * m / np.sqrt(cfg.hd + cfg.qk_rope_dim)
+
+
+def _mla_rope(x, positions, cfg):
+    out = apply_rope(x, positions, cfg.rope_theta,
+                     freqs=rope_freqs_of(cfg, x.shape[-1]))
+    if cfg.rope_factor > 1 and cfg.rope_mscale_all_dim:
+        # YaRN scales cos and sin by mscale / mscale_all_dim (1 where
+        # they are equal, as in DeepSeek-V3.2)
+        att = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+               / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+        if att != 1.0:
+            out = (out.astype(jnp.float32) * att).astype(x.dtype)
+    return out
+
+
+def mla_q_latent(p, x):
+    """The normed query latent [.., q_lora_rank] (V3.2's indexer reads it)."""
+    return rms_norm(x @ p["w_dq"], p["q_norm_g"])
+
+
 def mla_q_proj(p, x, cfg, positions):
     """x: [B(, S), D] -> q_nope [B(,S),nh,hd], q_pe [B(,S),nh,dr] (roped)."""
     nh, hd, dr = cfg.n_heads, cfg.hd, cfg.qk_rope_dim
     lead = x.shape[:-1]
-    q = rms_norm(x @ p["w_dq"], p["q_norm_g"]) @ p["w_uq"]
+    q = mla_q_latent(p, x) @ p["w_uq"]
     q = q.reshape(*lead, nh, hd + dr)
     q_nope, q_pe = q[..., :hd], q[..., hd:]
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    q_pe = _mla_rope(q_pe, positions, cfg)
     return q_nope, q_pe
 
 
@@ -223,8 +302,13 @@ def mla_kv_entry(p, x, cfg, positions):
     kv = x @ p["w_dkv"]
     c, k_pe = kv[..., :dc], kv[..., dc:]
     c = rms_norm(c, p["kv_norm_g"])
-    k_pe = apply_rope(k_pe, positions, cfg.rope_theta)
+    k_pe = _mla_rope(k_pe, positions, cfg)
     return jnp.concatenate([c, k_pe], axis=-1)
+
+
+# heads a prefill attends at once: at 128 heads and a 7168-token prompt one
+# key chunk's float32 scores over every head are 3.8 GB, over 16 0.47 GB
+MLA_HEAD_BLOCK = 16
 
 
 def mla_prefill_attention(p, x, cfg, positions, *, chunk: int = 1024):
@@ -244,9 +328,9 @@ def mla_prefill_attention(p, x, cfg, positions, *, chunk: int = 1024):
     k_pe_b = jnp.broadcast_to(k_pe[:, :, None, :], (B, S, nh, dr))
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
     k = jnp.concatenate([k_nope, k_pe_b], axis=-1)
-    # pad v with zeros so q/k/v share the last dim for the blocked kernel
-    v_pad = jnp.concatenate([v, jnp.zeros((B, S, nh, dr), v.dtype)], axis=-1)
-    out = blocked_causal_attention(q, k, v_pad, chunk=chunk)[..., :hd]
+    out = blocked_causal_attention(q, k, v, chunk=chunk,
+                                   scale=mla_softmax_scale(cfg),
+                                   head_block=MLA_HEAD_BLOCK)
     return out.reshape(B, S, nh * hd) @ p["wo"], entry
 
 
@@ -265,7 +349,7 @@ def mla_absorbed_decode(p, xq, cfg, fetched, valid, positions):
                        w_uk.astype(jnp.float32))
     c = fetched[..., :dc].astype(jnp.float32)                    # [B,k,dc]
     k_pe = fetched[..., dc:].astype(jnp.float32)                 # [B,k,dr]
-    scale = 1.0 / np.sqrt(hd + dr)
+    scale = mla_softmax_scale(cfg)
     s = (jnp.einsum("bhc,bkc->bhk", q_lat, c)
          + jnp.einsum("bhr,bkr->bhk", q_pe.astype(jnp.float32), k_pe)) * scale
     s = jnp.where(valid[:, None, :], s, NEG_INF)

@@ -8,6 +8,7 @@ Parameters are plain pytrees of jnp arrays.  Every leaf is declared through
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -74,10 +75,39 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: [..., S, H, hd] or [..., S, hd]; positions: [..., S]."""
+def yarn_freqs(head_dim: int, theta: float, factor: float, original: int,
+               beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's RoPE frequencies (arXiv:2309.00071, as DeepSeek-V3's
+    inference code computes them): dims that turn fewer than ``beta_slow``
+    times over the original context are slowed by ``factor``, those that
+    turn more than ``beta_fast`` times keep their frequency, and a linear
+    ramp blends the dims between."""
+    def corr_dim(rotations):
+        return (head_dim * math.log(original / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(beta_fast)), 0)
+    high = min(math.ceil(corr_dim(beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    # in float64, rounded to float32 once at the end
+    ramp = np.clip((np.arange(head_dim // 2) - low) / (high - low), 0, 1)
+    freqs = 1.0 / (theta ** (np.arange(0, head_dim, 2) / head_dim))
+    smooth = 1 - ramp
+    return (freqs / factor * (1 - smooth) + freqs * smooth).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor: 0.1 mscale ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x, positions, theta: float, freqs=None):
+    """x: [..., S, H, hd] or [..., S, hd]; positions: [..., S].  Rotates
+    adjacent pairs (2i, 2i+1) by ``freqs`` ([hd/2]; default plain RoPE of
+    base ``theta``)."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                              # [hd/2]
+    if freqs is None:
+        freqs = rope_freqs(hd, theta)                          # [hd/2]
     ang = positions[..., None].astype(jnp.float32) * freqs      # [..., S, hd/2]
     if x.ndim == ang.ndim + 1:                                  # head axis present
         ang = ang[..., None, :]
@@ -131,20 +161,38 @@ def repeat_kv(k, n_rep: int):
 
 
 def blocked_causal_attention(q, k, v, *, chunk: int = 1024,
-                             window: int = 0) -> jnp.ndarray:
+                             window: int = 0, scale: Optional[float] = None,
+                             head_block: int = 0) -> jnp.ndarray:
     """Memory-bounded causal attention via lax.scan over KV chunks
     (online softmax).  q,k,v: [B, S, H, hd] (k/v already head-repeated).
-    ``window`` > 0 enables sliding-window masking.
+    ``window`` > 0 enables sliding-window masking; ``scale`` defaults to
+    1/sqrt(hd).  ``head_block`` > 0 runs the heads in groups of that many,
+    one after another (heads are independent, so each group's result is
+    the one the whole would give): the float32 scores of one key chunk
+    then span a group, not every head.
     """
     B, S, H, hd = q.shape
-    scale = 1.0 / np.sqrt(hd)
+    if head_block and head_block < H:
+        assert H % head_block == 0, (H, head_block)
+        G = H // head_block
+
+        def group(a):          # [B, S, H, d] -> [G, B, S, hb, d]
+            return jnp.moveaxis(a.reshape(B, S, G, head_block, a.shape[-1]),
+                                2, 0)
+        out = jax.lax.map(
+            lambda qkv: blocked_causal_attention(
+                *qkv, chunk=chunk, window=window, scale=scale),
+            (group(q), group(k), group(v)))
+        return jnp.moveaxis(out, 0, 2).reshape(B, S, H, v.shape[-1])
+    scale = 1.0 / np.sqrt(hd) if scale is None else scale
     n_chunks = max(S // chunk, 1)
     chunk = S // n_chunks
     qf = (q.astype(jnp.float32) * scale).transpose(0, 2, 1, 3)   # [B,H,S,hd]
     kf = k.astype(jnp.float32).transpose(0, 2, 1, 3)
     vf = v.astype(jnp.float32).transpose(0, 2, 1, 3)
     kc = kf.reshape(B, H, n_chunks, chunk, hd).transpose(2, 0, 1, 3, 4)
-    vc = vf.reshape(B, H, n_chunks, chunk, hd).transpose(2, 0, 1, 3, 4)
+    vc = vf.reshape(B, H, n_chunks, chunk, vf.shape[-1]).transpose(
+        2, 0, 1, 3, 4)
     q_pos = jnp.arange(S)
 
     def body(carry, xs):
@@ -165,7 +213,7 @@ def blocked_causal_attention(q, k, v, *, chunk: int = 1024,
 
     init = (jnp.full((B, H, S), -1e30, jnp.float32),
             jnp.zeros((B, H, S), jnp.float32),
-            jnp.zeros((B, H, S, hd), jnp.float32))
+            jnp.zeros((B, H, S, vf.shape[-1]), jnp.float32))
     (m, l, acc), _ = jax.lax.scan(body, init, (kc, vc, jnp.arange(n_chunks)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.transpose(0, 2, 1, 3).astype(q.dtype)             # [B,S,H,hd]
@@ -203,8 +251,9 @@ def decode_attention(q, k_cache, v_cache, length_mask):
 # ---------------------------------------------------------------------------
 
 
-def mlp_param_specs(cfg) -> Dict[str, ParamSpec]:
-    d, f = cfg.d_model, cfg.d_ff
+def mlp_param_specs(cfg, d_ff: Optional[int] = None
+                    ) -> Dict[str, ParamSpec]:
+    d, f = cfg.d_model, d_ff or cfg.d_ff
     return {
         "w_gate": ParamSpec((d, f), ("D", "F")),
         "w_up": ParamSpec((d, f), ("D", "F")),

@@ -5,7 +5,11 @@ stacked parameters (keeping HLO size independent of depth):
 
   - ``dense``        one (attn + MLP) layer per iteration
   - ``moe``          one (attn + MoE) layer per iteration (dbrx / mixtral)
-  - ``mla_moe``      one (MLA attn + MoE) layer (deepseek-v32)
+  - ``mla_dense``    one (MLA attn + MLP) layer: deepseek-v32's leading
+                     ``first_k_dense`` layers, at the dense width
+  - ``mla_moe``      one (MLA attn + MoE) layer: deepseek-v32's other
+                     layers, each holding its ``n_experts_held`` experts
+                     and the shared expert (models/moe.py)
   - ``lg_super``     gemma3 super-block: 5 local-window layers + 1 global
   - ``zamba_super``  zamba2 super-block: 6 Mamba2 layers + tied shared-attn
   - ``mamba_tail``   trailing plain Mamba2 layers (zamba2: 81 = 13*6 + 3)
@@ -91,8 +95,11 @@ def build_segments(cfg: ModelConfig) -> List[Segment]:
         return [Segment("lg_super", cfg.n_layers // period, period,
                         window=cfg.local_window)]
     if cfg.mla:
-        return [Segment("mla_moe" if cfg.n_experts else "mla_dense",
-                        cfg.n_layers, 1)]
+        if not cfg.n_experts:
+            return [Segment("mla_dense", cfg.n_layers, 1)]
+        k = cfg.first_k_dense
+        return ([Segment("mla_dense", k, 1)] if k else []) + [
+            Segment("mla_moe", cfg.n_layers - k, 1)]
     if cfg.n_experts:
         return [Segment("moe", cfg.n_layers, 1, window=cfg.sliding_window)]
     return [Segment("dense", cfg.n_layers, 1, window=cfg.sliding_window)]
@@ -144,20 +151,29 @@ def _stack(specs, n: int):
     return jax.tree.map(one, specs, is_leaf=lambda x: isinstance(x, ParamSpec))
 
 
-def _attn_layer_specs(cfg) -> Dict[str, Any]:
+def _is_moe(seg: Segment, cfg: ModelConfig) -> bool:
+    """Whether the segment's layers hold experts (an MLA model's leading
+    dense layers do not)."""
+    return bool(cfg.n_experts) and seg.kind != "mla_dense"
+
+
+def _attn_layer_specs(cfg, moe_layer: Optional[bool] = None
+                      ) -> Dict[str, Any]:
+    if moe_layer is None:
+        moe_layer = bool(cfg.n_experts)
     p: Dict[str, Any] = {"ln1": _norm(cfg), "ln2": _norm(cfg)}
     p["attn"] = (dsa.mla_param_specs(cfg) if cfg.mla
                  else attn_param_specs(cfg))
     if cfg.sac.enabled:
         p["idx"] = dsa.indexer_param_specs(cfg)
-    p["mlp"] = (moe.moe_param_specs(cfg) if cfg.n_experts
-                else mlp_param_specs(cfg))
+    p["mlp"] = (moe.moe_param_specs(cfg) if moe_layer
+                else mlp_param_specs(cfg, cfg.d_ff_dense or cfg.d_ff))
     return p
 
 
 def segment_specs(seg: Segment, cfg: ModelConfig):
     if seg.kind in ("dense", "moe", "mla_dense", "mla_moe"):
-        return _stack(_attn_layer_specs(cfg), seg.n)
+        return _stack(_attn_layer_specs(cfg, _is_moe(seg, cfg)), seg.n)
     if seg.kind == "lg_super":
         one = _attn_layer_specs(cfg)
         return _stack({"local": _stack(one, cfg.local_global_ratio),
@@ -197,22 +213,32 @@ def model_param_specs(cfg: ModelConfig) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
-def _mlp_apply(p_mlp, x, cfg, *, decode: bool = False):
-    """MLP or MoE on [B, S, D]; returns (out, aux).
+def _mlp_apply(p_mlp, x, cfg, *, decode: bool = False,
+               moe_layer: Optional[bool] = None):
+    """MLP or MoE on [B, S, D]; returns (out, aux, counts).
 
+    ``counts`` ([B, S, 2] int32, or None) are the held-expert layer's
+    routed assignments on held experts and those dropped
+    (models/moe.py ``held_expert_block``).
     Grouped dispatch applies to full-sequence (train/prefill) calls only:
     decode steps route a handful of tokens — grouping them fragments the
     expert batches and regresses the collective term (§Perf B-series).
     """
+    if moe_layer is None:
+        moe_layer = bool(cfg.n_experts)
+    if moe_layer and cfg.router == "noaux_tc":
+        # its scopes (router, experts, shared_expert) name its parts
+        out, counts = moe.held_expert_block(p_mlp, x, cfg)
+        return out, jnp.float32(0), counts
     with jax.named_scope("mlp"):
-        if cfg.n_experts:
+        if moe_layer:
             groups = 1 if decode else _opt("moe_groups", 1)
             out, aux = moe.moe_block(p_mlp, x, cfg, groups=groups)
-            return out, aux
+            return out, aux, None
         h = constrain(x @ p_mlp["w_gate"], ("B", "Sq", "F"))
         h = jax.nn.silu(h) * (x @ p_mlp["w_up"])
         out = h @ p_mlp["w_down"]
-        return out, jnp.float32(0)
+        return out, jnp.float32(0), None
 
 
 def _attn_fwd(p, x, cfg, positions, window):
@@ -232,11 +258,14 @@ def _attn_fwd(p, x, cfg, positions, window):
         out, (k, v) = dense_attention_block(p["attn"], xn, cfg, positions,
                                             window=window)
         entry = dsa.pack_kv_entry(k, v)
-    idx_keys = (dsa.indexer_keys(p["idx"], xn) if cfg.sac.enabled else None)
+    idx_keys = (dsa.indexer_keys(p["idx"], xn, cfg, positions)
+                if cfg.sac.enabled else None)
     warm = None
     w = _opt("warmup_w", 0)
     if w and cfg.sac.enabled:
-        scores = dsa.indexer_scores(p["idx"], xn[:, -1], idx_keys, cfg)
+        scores = dsa.indexer_scores(p["idx"], xn[:, -1], idx_keys, cfg,
+                                    p_attn=p["attn"],
+                                    positions=positions[:, -1])
         if window:
             # windowed layers only ever select from the trailing window
             # at decode time — seeding anything older is guaranteed waste
@@ -251,11 +280,12 @@ def _attn_fwd(p, x, cfg, positions, window):
     return out, entry, idx_keys, warm
 
 
-def _layer_fwd(p, x, cfg, positions, window):
+def _layer_fwd(p, x, cfg, positions, window, moe_layer=None):
     """Full (attn + mlp) layer.  Returns (x', entry, idx_keys, warm, aux)."""
     delta, entry, idx_keys, warm = _attn_fwd(p, x, cfg, positions, window)
     x = constrain(x + delta, ("B", "S", "D"))
-    out, aux = _mlp_apply(p["mlp"], rms_norm(x, p["ln2"]), cfg)
+    out, aux, _ = _mlp_apply(p["mlp"], rms_norm(x, p["ln2"]), cfg,
+                             moe_layer=moe_layer)
     x = constrain(x + out, ("B", "S", "D"))
     return x, entry, idx_keys, warm, aux
 
@@ -286,7 +316,8 @@ def segment_fwd(seg: Segment, cfg: ModelConfig, shared_params=None,
     if seg.kind in ("dense", "moe", "mla_dense", "mla_moe"):
         def body(x, p, positions):
             x, entry, ikeys, wm, aux = _layer_fwd(p, x, cfg, positions,
-                                                  seg.window)
+                                                  seg.window,
+                                                  _is_moe(seg, cfg))
             return x, stack_entries([entry], [ikeys], [wm]), aux
         return body
 
@@ -367,7 +398,7 @@ def _attn_decode(p, x, cfg, ctx, kv_slice, idx_slice, window, hbuf=None):
         return delta, own, new_key, None, None, None
     # SAC path: indexer -> top-k -> fetch -> sparse attention
     with jax.named_scope("indexer"):
-        new_key = dsa.indexer_keys(p["idx"], xn)
+        new_key = dsa.indexer_keys(p["idx"], xn, cfg, positions)
     if hbuf is None:
         delta = sac_core.sparse_attend(
             p["attn"], p["idx"], xn, cfg, kv_slice, idx_slice, cache_len,
@@ -390,14 +421,20 @@ def _attn_decode(p, x, cfg, ctx, kv_slice, idx_slice, window, hbuf=None):
     return delta, own, new_key, hbuf, hits, misses
 
 
-def _layer_decode(p, x, cfg, ctx, kv_slice, idx_slice, window, hbuf=None):
+def _layer_decode(p, x, cfg, ctx, kv_slice, idx_slice, window, hbuf=None,
+                  moe_layer=None):
+    """Returns (x', own, new_key, hbuf', hits, misses, counts): ``counts``
+    ([B, 2] int32 or None) are the expert layer's (_mlp_apply)."""
     delta, own, new_key, hbuf2, hits, misses = _attn_decode(
         p, x, cfg, ctx, kv_slice, idx_slice, window, hbuf)
     x = x + delta
-    out, _ = _mlp_apply(p["mlp"], rms_norm(x, p["ln2"])[:, None, :], cfg,
-                        decode=True)
+    out, _, counts = _mlp_apply(p["mlp"], rms_norm(x, p["ln2"])[:, None, :],
+                                cfg, decode=True, moe_layer=moe_layer)
     x = x + out[:, 0]
-    return constrain(x, ("B", "D")), own, new_key, hbuf2, hits, misses
+    if counts is not None:
+        counts = counts[:, 0]
+    return (constrain(x, ("B", "D")), own, new_key, hbuf2, hits, misses,
+            counts)
 
 
 def _hb_layer(hb, i):
@@ -430,7 +467,8 @@ def segment_decode(seg: Segment, cfg: ModelConfig, shared_params=None):
 
     (x, p_slice, kv_slices [a,B,S,d], idx_slices, hbuf_slices, rec_slice,
      ctx) -> (x', new_entries [a,B,d], new_keys [a,B,di], new_hbuf,
-              (hits [B], misses [B]) | None, new_rec)
+              (hits [B], misses [B]) | None, new_rec, moe_counts [B, 2] |
+              None)
 
     ``hbuf_slices`` is the segment's per-iteration stack of HiSparse
     hot-buffer states ([a, ...] leading axes) or None; hit/miss counts
@@ -438,11 +476,11 @@ def segment_decode(seg: Segment, cfg: ModelConfig, shared_params=None):
     """
     if seg.kind in ("dense", "moe", "mla_dense", "mla_moe"):
         def body(x, p, kv, ik, hb, rec, ctx):
-            x, own, key, hb2, h, m = _layer_decode(
+            x, own, key, hb2, h, m, c = _layer_decode(
                 p, x, cfg, ctx, kv[0], None if ik is None else ik[0],
-                seg.window, _hb_layer(hb, 0))
+                seg.window, _hb_layer(hb, 0), _is_moe(seg, cfg))
             return (x, own[None], key[None], _hb_stack([hb2]),
-                    _hm_sum([h], [m]), rec)
+                    _hm_sum([h], [m]), rec, c)
         return body
 
     if seg.kind == "lg_super":
@@ -450,19 +488,19 @@ def segment_decode(seg: Segment, cfg: ModelConfig, shared_params=None):
             owns, keys, hbs, hs, ms = [], [], [], [], []
             for i in range(cfg.local_global_ratio):
                 pl = jax.tree.map(lambda a: a[i], p["local"])
-                x, own, key, hb2, h, m = _layer_decode(
+                x, own, key, hb2, h, m, _ = _layer_decode(
                     pl, x, cfg, ctx, kv[i], None if ik is None else ik[i],
                     cfg.local_window, _hb_layer(hb, i))
                 owns.append(own); keys.append(key)
                 hbs.append(hb2); hs.append(h); ms.append(m)
             g = cfg.local_global_ratio
-            x, own, key, hb2, h, m = _layer_decode(
+            x, own, key, hb2, h, m, _ = _layer_decode(
                 p["global"], x, cfg, ctx, kv[g],
                 None if ik is None else ik[g], 0, _hb_layer(hb, g))
             owns.append(own); keys.append(key)
             hbs.append(hb2); hs.append(h); ms.append(m)
             return (x, jnp.stack(owns), jnp.stack(keys), _hb_stack(hbs),
-                    _hm_sum(hs, ms), rec)
+                    _hm_sum(hs, ms), rec, None)
         return body
 
     if seg.kind == "zamba_super":
@@ -475,19 +513,19 @@ def segment_decode(seg: Segment, cfg: ModelConfig, shared_params=None):
                                              rms_norm(x, pl["ln"]), cfg, st)
                 x = x + out
                 new_rec.append(st2)
-            x, own, key, hb2, h, m = _layer_decode(
+            x, own, key, hb2, h, m, _ = _layer_decode(
                 shared_params, x, cfg, ctx, kv[0],
                 None if ik is None else ik[0], 0, _hb_layer(hb, 0))
             rec_out = jax.tree.map(lambda *a: jnp.stack(a), *new_rec)
             return (x, own[None], key[None], _hb_stack([hb2]),
-                    _hm_sum([h], [m]), rec_out)
+                    _hm_sum([h], [m]), rec_out, None)
         return body
 
     if seg.kind == "mamba_tail":
         def body(x, p, kv, ik, hb, rec, ctx):
             out, rec2 = ssm.mamba2_decode(p["mamba"], rms_norm(x, p["ln"]),
                                           cfg, rec)
-            return x + out, None, None, None, None, rec2
+            return x + out, None, None, None, None, rec2, None
         return body
 
     if seg.kind == "xlstm_super":
@@ -504,7 +542,7 @@ def segment_decode(seg: Segment, cfg: ModelConfig, shared_params=None):
             out, s2 = ssm.slstm_decode(ps, rms_norm(x, ps["ln"]), cfg, s_rec)
             x = x + out
             m_out = jax.tree.map(lambda *a: jnp.stack(a), *new_m)
-            return x, None, None, None, None, (m_out, s2)
+            return x, None, None, None, None, (m_out, s2), None
         return body
 
     raise ValueError(seg.kind)
@@ -704,6 +742,7 @@ class TransformerLM:
         use_idx = idx_pool is not None and self.mode == "sac"
         new_entries, new_keys = [], []
         hits_l, misses_l = [], []     # per-kv-layer [l, B] blocks, in order
+        moe_counts = []               # per expert segment [B, 2]
         kv_off = 0
         for si, seg in enumerate(self.segments):
             body = segment_decode(seg, cfg, params.get("shared"))
@@ -735,9 +774,9 @@ class TransformerLM:
                         ik = (jax.lax.dynamic_slice_in_dim(
                             idx_pool, _off + i * _a, _a, 0) if use_idx
                             else None)
-                    x, own, keys, hb2, hm, rc2 = _body(x, p, kv, ik, hb,
-                                                       rc, ctx)
-                    return x, (own, keys, hb2, hm, rc2)
+                    x, own, keys, hb2, hm, rc2, mc = _body(x, p, kv, ik, hb,
+                                                           rc, ctx)
+                    return x, (own, keys, hb2, hm, rc2, mc)
 
                 xs = (params["segments"][si],
                       jnp.arange(seg.n, dtype=jnp.int32), hb_g, rec)
@@ -760,16 +799,18 @@ class TransformerLM:
 
                 def scan_body(x, xs, _body=body):
                     p, kv, ik, hb, rc = xs
-                    x, own, keys, hb2, hm, rc2 = _body(x, p, kv, ik, hb,
-                                                       rc, ctx)
-                    return x, (own, keys, hb2, hm, rc2)
+                    x, own, keys, hb2, hm, rc2, mc = _body(x, p, kv, ik, hb,
+                                                           rc, ctx)
+                    return x, (own, keys, hb2, hm, rc2, mc)
 
                 xs = (params["segments"][si], kv_g, ik_g, hb_g, rec)
             # "layers": what the scan itself does (each layer's slice of
             # the stacked weights, pools and hot tier; stacking its outputs)
             with jax.named_scope("layers"):
-                x, (own, keys, hb2, hm, rec2) = jax.lax.scan(scan_body, x,
-                                                             xs)
+                x, (own, keys, hb2, hm, rec2, mc) = jax.lax.scan(
+                    scan_body, x, xs)
+            if mc is not None:
+                moe_counts.append(mc.sum(0))
             if own is not None:
                 new_entries.append(own.reshape(-1, B, own.shape[-1]))
                 new_keys.append(keys.reshape(-1, B, keys.shape[-1]))
@@ -817,6 +858,11 @@ class TransformerLM:
                 state["buf_misses"] = ml.sum(0)
                 state["pf_inserted"] = hot.pf_inserted.sum(0) - pf_ins0
                 state["pf_useful"] = hot.pf_used.sum(0) - pf_use0
+        if moe_counts:
+            # per slot since its admission (the engine's splice zeroes a
+            # slot's row), over the expert layers: routed assignments on
+            # held experts, and those dropped
+            state["moe_counts"] = state["moe_counts"] + sum(moe_counts)
         state["cache_len"] = cache_len + 1
         with jax.named_scope("lm_head"):
             x = rms_norm(x, params["final_norm"])
@@ -860,6 +906,9 @@ class TransformerLM:
                 # per-step speculative-prefetch outcomes (fetch pipeline)
                 state["pf_inserted"] = jnp.zeros((batch,), jnp.int32)
                 state["pf_useful"] = jnp.zeros((batch,), jnp.int32)
+        if any(_is_moe(s, cfg) for s in self.segments) \
+                and cfg.router == "noaux_tc":
+            state["moe_counts"] = jnp.zeros((batch, 2), jnp.int32)
         for si, seg in enumerate(self.segments):
             shapes = _stacked_rec_shapes(seg, cfg, batch)
             if shapes is not None:

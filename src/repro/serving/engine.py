@@ -97,6 +97,12 @@ class EngineStats:
                                     # SLO-aware admission policy)
     device_reads: int = 0           # device values the engine waited for
                                     # and copied to the host
+    moe_held_assignments: int = 0   # routed (token, expert) assignments
+                                    # of finished requests that landed on
+                                    # an expert this chip holds
+    moe_dropped: int = 0            # of them, those no held expert
+                                    # computed (the expert layer is
+                                    # dropless: 0)
     traffic: TrafficStats = dataclasses.field(default_factory=TrafficStats)
     # measured per-layer hot-tier outcomes ([L] arrays, accumulated per
     # step) — the LayerSizer's miss-rate signal (serving/arbiter.py)
@@ -1045,7 +1051,8 @@ class Engine:
             if key == "hot_buf":
                 new_state[key] = hisparse.reset_lane(dst, slot)
                 continue
-            if key in ("buf_hits", "buf_misses", "pf_inserted", "pf_useful"):
+            if key in ("buf_hits", "buf_misses", "pf_inserted", "pf_useful",
+                       "moe_counts"):
                 new_state[key] = dst.at[slot].set(0)
                 continue
             if key in ("buf_hits_l", "buf_misses_l"):   # [L, B] layouts
@@ -1342,6 +1349,12 @@ class Engine:
                 # last prompt token, not a generated one
                 req.out_tokens = self.slot_tokens[s][1:]
                 finished.append(req)
+                if "moe_counts" in self.state:
+                    # the slot's expert counters since its admission
+                    # (the splice zeroes them): one read a request
+                    moe = self._read(self.state["moe_counts"][s])
+                    self.stats.moe_held_assignments += int(moe[0])
+                    self.stats.moe_dropped += int(moe[1])
                 dev = self.sac.device_of(req.request_id)
                 # radix lifecycle at departure: unpin the request's
                 # prefix path, retain the pages the index registered

@@ -165,3 +165,47 @@ def test_pooled_fetch_compiles_on_2x2(topo):
     idx = _sds((B, k), jnp.int32, NamedSharding(mesh, P()))
     compiled = _compile(fetch, pool, idx)
     assert "all-reduce" in compiled.as_text()
+
+
+@pytest.fixture(scope="module")
+def deepseek(one_chip):
+    """One chip's share of DeepSeek-V3.2 as the benchmark serves it
+    (chipbench/configs/deepseek-v32.json: 3 dense + 4 expert layers, 8
+    held of 256 experts, 16160 ids), built as ``Engine(..., prefetch=True)``
+    builds it, with parameter and serve-state shapes on one chip."""
+    import dataclasses
+    cfg = dataclasses.replace(get_config("deepseek-v32"), n_layers=7,
+                              n_experts_held=8, vocab=16160)
+    sac = cfg.sac
+    model = build_model(cfg, mode="sac", opts={
+        "prefetch_width": sac.prefetch_width,
+        "score_margin": sac.score_margin,
+        "warmup_w": sac.warmup_entries})
+    params = _on(jax.eval_shape(model.init, jax.random.PRNGKey(0)), one_chip)
+    state = _on(model.serve_state_shapes(
+        SLOTS, MAX_CTX, device_buffer=sac.device_buffer_size), one_chip)
+    return cfg, model, params, state
+
+
+def test_deepseek_share_decode_fits_one_chip(deepseek, one_chip):
+    cfg, model, params, state = deepseek
+    compiled = _compile(model.decode, params, state,
+                        _sds((SLOTS,), jnp.int32, one_chip))
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < HBM_BYTES, total
+
+
+def test_deepseek_share_prefill_fits_one_chip(deepseek, one_chip):
+    """The prefill at the longest prompt, beside the engine's 4-slot
+    state, which lives on the chip while a request is admitted."""
+    cfg, model, params, state = deepseek
+    compiled = _compile(lambda p, t: model.prefill(p, t), params,
+                        _sds((1, PROMPT), jnp.int32, one_chip))
+    mem = compiled.memory_analysis()
+    state_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(state))
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes + state_bytes)
+    assert total < HBM_BYTES, total

@@ -79,3 +79,26 @@ def test_hot_tier_holds_no_entry_values():
             continue
         assert not (dtype in ("bf16", "f32") and "/hot_tier/" in rest), name
         assert len(dims) < 2 or dims[-2] not in (buf, buf + 1), (name, dims)
+
+
+def test_deepseek_decode_names_its_expert_layer():
+    """DeepSeek-V3.2's decode keeps the ten scopes (its MLA under
+    ``attention``, the V3.2 indexer under ``indexer``, its dense layers'
+    MLP under ``mlp``) and names the expert layer's parts ``router``,
+    ``experts`` and ``shared_expert``."""
+    cfg = get_config("deepseek-v32").reduced()
+    sac = cfg.sac
+    model = build_model(cfg, mode="sac", opts={
+        "prefetch_width": sac.prefetch_width,
+        "score_margin": sac.score_margin, "warmup_w": sac.warmup_entries})
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = model.serve_state_shapes(2, 64,
+                                     device_buffer=sac.device_buffer_size)
+    lowered = jax.jit(model.decode).lower(
+        params, state, jax.ShapeDtypeStruct((2,), jnp.int32))
+    names = set(SCOPES) | {"router", "experts", "shared_expert"}
+    assert names <= _scopes(lowered)
+    text = lowered.as_text(dialect="hlo", debug_info=True)
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    # the expert layer's scopes sit outside ``mlp``
+    assert not any("/mlp/" in p and "/experts/" in p for p in paths)
