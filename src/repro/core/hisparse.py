@@ -23,12 +23,24 @@ All scatters use a padding "sink" row (index ``buf``/``S``) for inactive
 lanes so no two active lanes ever write the same slot — scatter-set order
 is therefore deterministic.
 
+On a TPU a scatter or gather costs about 10 ns an index whether its lane
+does work or writes the sink, so the passes are arranged by how many
+lanes they touch.  Lane-wide, over the step's k lanes: the page-table
+lookup and one scatter of the hit mask; the dedupe of repeated
+positions (a scatter-min and a gather) only where lanes can repeat — not
+for ``lax.top_k``'s lanes, which are distinct (``distinct``).  Dense:
+the victim order (an argsort of the slots), the miss ranks (a cumsum),
+and the hit slots' clocks and prefetch flags.  Over compacted miss lanes:
+the fills, in chunks of ``_fill_width(k)`` lanes gathered by a dense
+compare of ranks, as many chunks as the row with the most fills needs.
+
 The returned ``hits``/``misses`` counts drive the transfer cost model:
 only misses cross the fabric (paper §5.5 — a larger buffer lowers miss
 traffic, which is exactly what Fig 14 measures).
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Sequence, Tuple, Union
 
 import jax
@@ -52,6 +64,10 @@ class BufferState(NamedTuple):
     by ``warm_insert`` that have not been demand-hit yet; ``pf_inserted``
     / ``pf_used`` are cumulative per-request counters, so prefetch
     precision is measured *in-graph* (``wasted == inserted - used``).
+
+    ``fill_chunks`` and ``miss_steps`` count, per request, the fill
+    chunks its demand swap-ins ran and the swap-ins that had a miss: how
+    far the miss compaction is engaged.
     """
     slot_pos: jnp.ndarray     # [B, buf]      global position held by slot (-1 empty)
     page_table: jnp.ndarray   # [B, S]        position -> slot (-1 not resident)
@@ -60,6 +76,8 @@ class BufferState(NamedTuple):
     pf_flag: jnp.ndarray      # [B, buf]      slot was prefetched, not yet used
     pf_inserted: jnp.ndarray  # [B]           cumulative warm-inserted entries
     pf_used: jnp.ndarray      # [B]           cumulative prefetched-then-hit
+    fill_chunks: jnp.ndarray  # [B]           cumulative demand fill chunks
+    miss_steps: jnp.ndarray   # [B]           cumulative swap-ins with a miss
 
 
 def init_buffer(batch: int, buf_size: int, seq_len: int) -> BufferState:
@@ -71,6 +89,8 @@ def init_buffer(batch: int, buf_size: int, seq_len: int) -> BufferState:
         pf_flag=jnp.zeros((batch, buf_size), bool),
         pf_inserted=jnp.zeros((batch,), jnp.int32),
         pf_used=jnp.zeros((batch,), jnp.int32),
+        fill_chunks=jnp.zeros((batch,), jnp.int32),
+        miss_steps=jnp.zeros((batch,), jnp.int32),
     )
 
 
@@ -81,77 +101,136 @@ def lookup(state: BufferState, idx: jnp.ndarray
     return slots, slots >= 0
 
 
-def _swap_in_one(slot_pos, page_table, last_use, clock, pf_flag, idx,
-                 valid):
-    """Single-request swap-in (vmapped over B).
+def _fill_width(lanes: int) -> int:
+    """Lanes per fill chunk for a pass over ``lanes`` candidate lanes:
+    about an eighth of them, in whole 128-lane tiles once an eighth
+    reaches one (2048 top-k lanes -> 256, 512 speculation lanes -> 64).
+    Shapes alone set it: a chunk costs its width in per-index work
+    however many of its lanes are active."""
+    m = -(-lanes // 8)
+    return m if m < 128 else -(-m // 128) * 128
 
-    idx: [k] positions requested this step (always in [0, S));
-    valid: [k] mask of real lanes.
 
-    Note: if ``k > buf`` overflow misses stay unbuffered; accounting of
-    hits is exact because reads happen before the swap-in.
-    """
-    buf = slot_pos.shape[0]
+def _first_occurrence(idx, lanes, S):
+    """Of one row's ``lanes`` ([k] mask), those whose position no earlier
+    lane of the mask holds: a scatter-min and a gather over the k lanes,
+    run only where lanes can repeat a position."""
     k = idx.shape[0]
-    S = page_table.shape[0]
     order = jnp.arange(k, dtype=jnp.int32)
+    key = jnp.where(lanes, idx, S)
+    first = jnp.full((S + 1,), k, jnp.int32).at[key].min(order)
+    return lanes & (first[key] == order)
 
+
+def _victim_order(slot_pos, last_use, protected):
+    """One row's slots in eviction order: empty slots first (lowest index
+    first), then least recently used, ``protected`` slots next to last
+    and DISABLED slots strictly last; ties go to the lower slot index."""
+    buf = slot_pos.shape[0]
+    key = jnp.where(slot_pos == EMPTY,
+                    jnp.arange(buf, dtype=jnp.int32) - _BIG,
+                    jnp.where(slot_pos == DISABLED, _BIG,
+                              jnp.where(protected, _BIG - 1, last_use)))
+    return jnp.argsort(key).astype(jnp.int32)
+
+
+def _fill_chunk(c, sp, pt, lu, pf, idx, lanes, rank, n_fill, victims,
+                clock, *, width, flag):
+    """Fill ranks [c * width, (c + 1) * width) of one row (vmapped over B).
+
+    Rank r maps the lane holding the r-th filling position to slot
+    ``victims[r]``.  The chunk's lanes are gathered densely (each rank
+    compared with every lane's) and its victims are a slice, so only the
+    read of the evicted positions and the five updates pay per index,
+    ``width`` each.  sp/pt/lu/pf carry a sink row at the end,
+    which every inactive lane writes; pf is int32 here, for the reason
+    the hit mask is.
+    """
+    buf = sp.shape[0] - 1
+    S = pt.shape[0] - 1
+    r = c * width + jnp.arange(width, dtype=jnp.int32)
+    active = r < n_fill
+    pos = jnp.where(lanes[None, :] & (rank[None, :] == r[:, None]),
+                    idx[None, :], 0).sum(1)                 # [width]
+    slot = jnp.where(active,
+                     jax.lax.dynamic_slice_in_dim(victims, c * width, width),
+                     buf)
+    old = sp[slot]                                          # evicted
+    pt = pt.at[jnp.where(old >= 0, old, S)].set(EMPTY)
+    pt = pt.at[jnp.where(active, pos, S)].set(slot)
+    sp = sp.at[slot].set(jnp.where(active, pos, EMPTY))
+    lu = lu.at[slot].set(clock)
+    pf = pf.at[slot].set(jnp.int32(flag))
+    return sp, pt, lu, pf
+
+
+def _fill(slot_pos, page_table, last_use, pf_flag, idx, lanes, n_fill,
+          victims, clock, flag):
+    """Map the first ``n_fill`` [B] of each row's ``lanes`` [B, k], in lane
+    order, into slots ``victims`` [B, buf] (ranks paired in order), stamp
+    them with ``clock`` and set their prefetch flag to ``flag``.
+
+    Runs ceil(max_b n_fill / width) chunks of ``width`` lanes: every fill
+    lands whatever its count.  Ranks are global, so the chunks touch
+    disjoint slots and positions.  Returns the four arrays and the chunks
+    each row's fills took [B].
+    """
+    k = idx.shape[1]
+    buf = slot_pos.shape[1]
+    S = page_table.shape[1]
+    width = _fill_width(k)
+    rank = jnp.cumsum(lanes.astype(jnp.int32), axis=1) - 1
+    # sink columns; the victim list is padded so a chunk's slice stays
+    # inside it (the last chunk starts below min(k, buf) <= buf)
+    sp = jnp.pad(slot_pos, ((0, 0), (0, 1)), constant_values=EMPTY)
+    pt = jnp.pad(page_table, ((0, 0), (0, 1)), constant_values=EMPTY)
+    lu = jnp.pad(last_use, ((0, 0), (0, 1)))
+    pf = jnp.pad(pf_flag.astype(jnp.int32), ((0, 0), (0, 1)))
+    victims = jnp.pad(victims, ((0, 0), (0, width)), constant_values=buf)
+    chunk = jax.vmap(functools.partial(_fill_chunk, width=width, flag=flag),
+                     in_axes=(None,) + (0,) * 10)
+
+    def body(c, carry):
+        return chunk(c, *carry, idx, lanes, rank, n_fill, victims, clock)
+
+    chunks = (n_fill + width - 1) // width
+    sp, pt, lu, pf = jax.lax.fori_loop(0, chunks.max(), body,
+                                       (sp, pt, lu, pf))
+    return sp[:, :buf], pt[:, :S], lu[:, :buf], pf[:, :buf] > 0, chunks
+
+
+def _demand_one(slot_pos, page_table, last_use, clock, pf_flag, idx, valid,
+                *, distinct):
+    """One row's lane-wide half of a swap-in (vmapped over B): lookup,
+    dedupe (only where lanes can repeat), the hit mask, the victim order,
+    and the dense updates of hit slots."""
+    S = page_table.shape[0]
+    buf = slot_pos.shape[0]
     slots = page_table[idx]                                # [k]
     hit = (slots >= 0) & valid
-    miss = (~hit) & valid
-    # dedupe repeated positions within idx: only the first VALID
-    # occurrence fills (invalid lanes must not shadow valid duplicates)
-    idx_dedup = jnp.where(valid, idx, S)
-    first_occ = jnp.full((S + 1,), k, jnp.int32).at[idx_dedup].min(order)
-    miss = miss & (first_occ[idx_dedup] == order)
-
-    # eviction order: empty slots first, then LRU, protected (current hits)
-    # second-to-last, DISABLED slots (per-layer sizing) strictly last and
-    # outside the assignable range.
-    prot = jnp.zeros((buf,), bool).at[jnp.where(hit, slots, buf - 1)].max(hit)
-    empty = slot_pos == EMPTY
-    disabled = slot_pos == DISABLED
-    key = jnp.where(empty, jnp.arange(buf, dtype=jnp.int32) - _BIG,
-                    jnp.where(disabled, _BIG,
-                              jnp.where(prot, _BIG - 1, last_use)))
-    victim_order = jnp.argsort(key).astype(jnp.int32)      # [buf]
-    n_slots = buf - disabled.astype(jnp.int32).sum()       # layer's size
-
-    miss_rank = jnp.cumsum(miss.astype(jnp.int32)) - 1     # [k]
-    fillable = miss & (miss_rank < n_slots)
-    assign = jnp.where(fillable,
-                       victim_order[jnp.clip(miss_rank, 0, buf - 1)],
-                       buf)                                # buf = sink row
-
-    # --- padded updates: row S / row buf are write sinks ---
-    pt = jnp.concatenate([page_table, jnp.full((1,), EMPTY)])
-    sp = jnp.concatenate([slot_pos, jnp.full((1,), EMPTY)])
-    old_pos = sp[assign]                                   # evicted position
-    pt = pt.at[jnp.where(old_pos >= 0, old_pos, S)].set(EMPTY)
-    pt = pt.at[jnp.where(fillable, idx, S)].set(assign)
-    page_table = pt[:S]
-
-    sp = sp.at[assign].set(jnp.where(fillable, idx, EMPTY))
-    slot_pos = sp[:buf]
-
-    touched = jnp.where(hit, slots, assign)                # in [0, buf]
-    lu = jnp.concatenate([last_use, jnp.zeros((1,), jnp.int32)])
-    last_use = lu.at[touched].set(clock)[:buf]
-
-    # prefetch accounting: a demand hit on a prefetched slot consumes its
-    # flag (counted once per slot — the scatter-max dedupes repeated idx);
-    # demand fills overwrite any stale flag on the victim slot.
-    hit_mask = jnp.zeros((buf + 1,), bool) \
-        .at[jnp.where(hit, slots, buf)].max(hit)[:buf]
-    pf_used = (pf_flag & hit_mask).astype(jnp.int32).sum()
-    pf = jnp.concatenate([pf_flag & ~hit_mask, jnp.zeros((1,), bool)])
-    pf_flag = pf.at[assign].set(False)[:buf]
-
-    return (slot_pos, page_table, last_use, pf_flag, pf_used,
-            hit.astype(jnp.int32).sum(), miss.astype(jnp.int32).sum())
+    miss = ~hit & valid
+    if not distinct:
+        # repeated positions: only the first VALID occurrence fills
+        # (invalid lanes must not shadow valid duplicates)
+        miss = miss & _first_occurrence(idx, valid, S)
+    # one scatter: the slots this step hits.  They are protected from
+    # eviction, stamped with the clock, and consume their prefetch flag
+    # (once per slot, whatever the lanes hitting it).  Every write to a
+    # slot is the same 1, so repeats need no combiner; an int32 target
+    # spares the index sort a scatter into bool costs on a TPU
+    hit_mask = jnp.zeros((buf + 1,), jnp.int32) \
+        .at[jnp.where(hit, slots, buf)].set(1)[:buf] > 0
+    victims = _victim_order(slot_pos, last_use, hit_mask)
+    n_slots = buf - (slot_pos == DISABLED).astype(jnp.int32).sum()
+    n_miss = miss.astype(jnp.int32).sum()
+    return (jnp.where(hit_mask, clock, last_use), pf_flag & ~hit_mask,
+            (pf_flag & hit_mask).astype(jnp.int32).sum(), miss,
+            jnp.minimum(n_miss, n_slots), victims,
+            hit.astype(jnp.int32).sum(), n_miss)
 
 
-def swap_in(state: BufferState, idx: jnp.ndarray, valid: jnp.ndarray
+def swap_in(state: BufferState, idx: jnp.ndarray, valid: jnp.ndarray,
+            distinct: bool = False
             ) -> Tuple[BufferState, jnp.ndarray, jnp.ndarray]:
     """Batched swap-in of a demand read.  idx: [B,k]; valid: [B,k].
 
@@ -160,14 +239,31 @@ def swap_in(state: BufferState, idx: jnp.ndarray, valid: jnp.ndarray
     in.  The values read are the caller's pool fetch either way — the hot
     tier changes *traffic*, never results.  Returns (state', hits [B],
     misses [B]).
+
+    Lane-wide (k lanes a row): the page-table lookup and the scatter of
+    the hit mask; with ``distinct`` False also the dedupe of repeated
+    positions (a scatter-min and a gather).  ``distinct`` says the valid
+    lanes hold distinct positions, as ``lax.top_k``'s do, and skips it.
+    Dense: the victim order (one argsort of the slots), the miss ranks,
+    and the hit slots' clocks and prefetch flags.  Over compacted miss
+    lanes, ``_fill_width(k)`` at a time: the page-table, ``slot_pos``,
+    ``last_use`` and ``pf_flag`` updates of the fills.  Misses beyond
+    the layer's slots (``k > buf``, or a layer shrunk by DISABLED slots)
+    stay unbuffered.
     """
     clock = state.clock + 1
-    (slot_pos, page_table, last_use, pf_flag, pf_used, hits,
-     misses) = jax.vmap(_swap_in_one)(
+    (last_use, pf_flag, pf_used, miss, n_fill, victims, hits,
+     misses) = jax.vmap(functools.partial(_demand_one, distinct=distinct))(
         state.slot_pos, state.page_table, state.last_use, clock,
         state.pf_flag, idx, valid)
-    return (BufferState(slot_pos, page_table, last_use, clock, pf_flag,
-                        state.pf_inserted, state.pf_used + pf_used),
+    slot_pos, page_table, last_use, pf_flag, chunks = _fill(
+        state.slot_pos, state.page_table, last_use, pf_flag, idx, miss,
+        n_fill, victims, clock, False)
+    return (state._replace(slot_pos=slot_pos, page_table=page_table,
+                           last_use=last_use, clock=clock, pf_flag=pf_flag,
+                           pf_used=state.pf_used + pf_used,
+                           fill_chunks=state.fill_chunks + chunks,
+                           miss_steps=state.miss_steps + (misses > 0)),
             hits, misses)
 
 
@@ -176,79 +272,55 @@ def swap_in(state: BufferState, idx: jnp.ndarray, valid: jnp.ndarray
 # ---------------------------------------------------------------------------
 
 
-def _warm_insert_one(slot_pos, page_table, last_use, clock, pf_flag, idx,
-                     valid):
-    """Single-request warm insert (vmapped over B).
+def _warm_one(slot_pos, page_table, last_use, clock, idx, valid, *,
+              distinct):
+    """One row's lane-wide half of a warm insert (vmapped over B).
 
     Insert-without-read: positions already resident are skipped (no hit
     counted, no recency bump for THEIR slots beyond what the demand path
     did), and the current step's working set — slots with
     ``last_use >= clock`` (this step's hits, demand fills, and earlier
-    warm inserts) — is never evicted.  Inserted slots get the current
-    clock: the speculation is that they are next step's hits, so they age
-    exactly like this step's demand entries.
+    warm inserts) — is never evicted.
     """
-    buf = slot_pos.shape[0]
-    w = idx.shape[0]
     S = page_table.shape[0]
-    order = jnp.arange(w, dtype=jnp.int32)
-
-    resident = page_table[idx] >= 0
-    want = valid & ~resident
-    idx_dedup = jnp.where(want, idx, S)
-    first_occ = jnp.full((S + 1,), w, jnp.int32).at[idx_dedup].min(order)
-    want = want & (first_occ[idx_dedup] == order)
-
-    empty = slot_pos == EMPTY
+    buf = slot_pos.shape[0]
+    want = valid & (page_table[idx] < 0)
+    if not distinct:
+        want = _first_occurrence(idx, want, S)
     disabled = slot_pos == DISABLED
-    prot = (last_use >= clock) & ~empty & ~disabled
-    key = jnp.where(empty, jnp.arange(buf, dtype=jnp.int32) - _BIG,
-                    jnp.where(disabled, _BIG,
-                              jnp.where(prot, _BIG - 1, last_use)))
-    victim_order = jnp.argsort(key).astype(jnp.int32)      # [buf]
+    prot = (last_use >= clock) & (slot_pos != EMPTY) & ~disabled
     avail = (buf - prot.astype(jnp.int32).sum()            # evictable slots
              - disabled.astype(jnp.int32).sum())
-
-    rank = jnp.cumsum(want.astype(jnp.int32)) - 1
-    fill = want & (rank < avail)
-    assign = jnp.where(fill, victim_order[jnp.clip(rank, 0, buf - 1)],
-                       buf)                                # buf = sink row
-
-    pt = jnp.concatenate([page_table, jnp.full((1,), EMPTY)])
-    sp = jnp.concatenate([slot_pos, jnp.full((1,), EMPTY)])
-    old_pos = sp[assign]
-    pt = pt.at[jnp.where(old_pos >= 0, old_pos, S)].set(EMPTY)
-    pt = pt.at[jnp.where(fill, idx, S)].set(assign)
-    page_table = pt[:S]
-
-    sp = sp.at[assign].set(jnp.where(fill, idx, EMPTY))
-    slot_pos = sp[:buf]
-
-    lu = jnp.concatenate([last_use, jnp.zeros((1,), jnp.int32)])
-    last_use = lu.at[assign].set(clock)[:buf]
-
-    pf = jnp.concatenate([pf_flag, jnp.zeros((1,), bool)])
-    pf_flag = pf.at[assign].set(fill)[:buf]
-
-    return (slot_pos, page_table, last_use, pf_flag,
-            fill.astype(jnp.int32).sum())
+    return (want, jnp.minimum(want.astype(jnp.int32).sum(), avail),
+            _victim_order(slot_pos, last_use, prot))
 
 
-def warm_insert(state: BufferState, idx: jnp.ndarray, valid: jnp.ndarray
+def warm_insert(state: BufferState, idx: jnp.ndarray, valid: jnp.ndarray,
+                distinct: bool = False
                 ) -> Tuple[BufferState, jnp.ndarray]:
     """Batched warm insert.  idx: [B, w]; valid: [B, w].
 
     Makes pool positions resident WITHOUT serving a read — no hit/miss is
     counted, current-step hits are never evicted, and already resident
-    positions are skipped.  Returns (state', inserted [B]); the
+    positions are skipped.  Inserted slots get the current clock: the
+    speculation is that they are next step's hits, so they age exactly
+    like this step's demand entries.  Returns (state', inserted [B]); the
     cumulative ``pf_inserted`` counter advances by the same amount.
+
+    The passes are ``swap_in``'s: a lane-wide lookup (and, unless
+    ``distinct``, the dedupe), then the fills over compacted lanes.
     """
-    (slot_pos, page_table, last_use, pf_flag, ins) = jax.vmap(
-        _warm_insert_one)(state.slot_pos, state.page_table, state.last_use,
-                          state.clock, state.pf_flag, idx, valid)
-    return (BufferState(slot_pos, page_table, last_use, state.clock,
-                        pf_flag, state.pf_inserted + ins, state.pf_used),
-            ins)
+    want, n_fill, victims = jax.vmap(
+        functools.partial(_warm_one, distinct=distinct))(
+            state.slot_pos, state.page_table, state.last_use, state.clock,
+            idx, valid)
+    slot_pos, page_table, last_use, pf_flag, _ = _fill(
+        state.slot_pos, state.page_table, state.last_use, state.pf_flag,
+        idx, want, n_fill, victims, state.clock, True)
+    return (state._replace(slot_pos=slot_pos, page_table=page_table,
+                           last_use=last_use, pf_flag=pf_flag,
+                           pf_inserted=state.pf_inserted + n_fill),
+            n_fill)
 
 
 def warm_lane(state: BufferState, lane, idx: jnp.ndarray,
@@ -315,6 +387,8 @@ def init_layered_buffer(n_layers: int, batch: int,
         pf_flag=jnp.zeros((n_layers, batch, buf_max), bool),
         pf_inserted=jnp.zeros((n_layers, batch), jnp.int32),
         pf_used=jnp.zeros((n_layers, batch), jnp.int32),
+        fill_chunks=jnp.zeros((n_layers, batch), jnp.int32),
+        miss_steps=jnp.zeros((n_layers, batch), jnp.int32),
     )
 
 
@@ -377,11 +451,10 @@ def resize_layers(state: BufferState, sizes: Sequence[int]) -> BufferState:
     def unflat(t):
         return t.reshape(L, B, *t.shape[1:])
 
-    return BufferState(
+    return state._replace(
         slot_pos=unflat(slot_pos),
         page_table=unflat(page_table), last_use=unflat(last_use),
-        clock=state.clock, pf_flag=unflat(pf_flag),
-        pf_inserted=state.pf_inserted, pf_used=state.pf_used)
+        pf_flag=unflat(pf_flag))
 
 
 def reset_lane(state: BufferState, lane: int) -> BufferState:
@@ -402,4 +475,6 @@ def reset_lane(state: BufferState, lane: int) -> BufferState:
         pf_flag=state.pf_flag.at[:, lane].set(False),
         pf_inserted=state.pf_inserted.at[:, lane].set(0),
         pf_used=state.pf_used.at[:, lane].set(0),
+        fill_chunks=state.fill_chunks.at[:, lane].set(0),
+        miss_steps=state.miss_steps.at[:, lane].set(0),
     )

@@ -109,8 +109,10 @@ def sparse_attend(p_attn: Dict, p_idx: Dict, x: jnp.ndarray, cfg: ModelConfig,
         fetched = fetch_fn(kv_pool_l, idx)
     if buf_state is not None:
         with jax.named_scope("hot_tier"):
-            buf_state, hits, misses = hisparse.swap_in(buf_state, idx,
-                                                       valid)
+            # top_k's lanes hold distinct positions, so the tier skips its
+            # dedupe; an injected selection may repeat one
+            buf_state, hits, misses = hisparse.swap_in(
+                buf_state, idx, valid, distinct=topk_fn is None)
             if speculate:
                 if p_idx_ is None:
                     p_idx_, p_valid = (
@@ -125,8 +127,9 @@ def sparse_attend(p_attn: Dict, p_idx: Dict, x: jnp.ndarray, cfg: ModelConfig,
                     # may issue (lanes are best-first) — traffic shaping
                     # only
                     p_valid = dsa.budget_mask(p_valid, pf_budget)
-                buf_state, _ = hisparse.warm_insert(buf_state, p_idx_,
-                                                    p_valid)
+                buf_state, _ = hisparse.warm_insert(
+                    buf_state, p_idx_, p_valid,
+                    distinct=prefetch_fn is None)
     with jax.named_scope("attention"):
         fetched = jnp.concatenate(
             [fetched, own_entry[:, None, :].astype(fetched.dtype)], axis=1)

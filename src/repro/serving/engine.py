@@ -103,6 +103,11 @@ class EngineStats:
     moe_dropped: int = 0            # of them, those no held expert
                                     # computed (the expert layer is
                                     # dropless: 0)
+    hot_tier_fill_chunks: int = 0   # fill chunks the hot tier's demand
+                                    # swap-ins ran for finished requests,
+                                    # summed over layers
+    hot_tier_miss_layer_steps: int = 0  # of their (layer, step) swap-ins,
+                                    # those with at least one miss
     traffic: TrafficStats = dataclasses.field(default_factory=TrafficStats)
     # measured per-layer hot-tier outcomes ([L] arrays, accumulated per
     # step) — the LayerSizer's miss-rate signal (serving/arbiter.py)
@@ -1355,6 +1360,16 @@ class Engine:
                     moe = self._read(self.state["moe_counts"][s])
                     self.stats.moe_held_assignments += int(moe[0])
                     self.stats.moe_dropped += int(moe[1])
+                if "hot_buf" in self.state:
+                    # the slot's miss-compaction engagement since its
+                    # admission (the splice resets its lane): one read a
+                    # request
+                    hot = self.state["hot_buf"]
+                    fills = self._read(jnp.stack(
+                        [hot.fill_chunks[:, s], hot.miss_steps[:, s]]))
+                    self.stats.hot_tier_fill_chunks += int(fills[0].sum())
+                    self.stats.hot_tier_miss_layer_steps += \
+                        int(fills[1].sum())
                 dev = self.sac.device_of(req.request_id)
                 # radix lifecycle at departure: unpin the request's
                 # prefix path, retain the pages the index registered

@@ -201,3 +201,43 @@ def test_counters_and_tokens_match_recorded(case):
     # the run exercises what it pins down
     last = got["steps"][-1]["totals"]
     assert last[0] > 0 and last[1] > 0 and last[2] > 0 and last[3] > 0
+
+
+@pytest.mark.parametrize("prefetch", [False, True])
+def test_fill_engagement_counters_add_up(prefetch):
+    """The hot tier's engagement counters, read once when a request
+    finishes, are the sums of that request's own steps: a miss layer-step
+    for every layer whose ``buf_misses_l`` was nonzero, and the chunks
+    its fills took.  Reading them costs no device read a step."""
+    from repro.core import hisparse
+    cfg = get_config("qwen2-1.5b").reduced()
+    eng = Engine(cfg, slots=2, max_ctx=96, prefetch=prefetch, seed=0)
+    width = hisparse._fill_width(cfg.sac.topk)
+    prompt = np.arange(40, dtype=np.int32) % cfg.vocab
+    req = Request(0, 0.0, len(prompt), 6, prompt)
+    eng.submit(req)
+    miss_steps = chunks = steps = 0
+    reads_a_step = []
+    slot = None
+    while True:
+        reads = eng.stats.device_reads
+        done = eng.step()
+        if slot is None:
+            slot = eng.slot_req.index(req)
+        misses = np.asarray(eng.state["buf_misses_l"])[:, slot]
+        n_slots = np.asarray(
+            (eng.state["hot_buf"].slot_pos[:, slot] != hisparse.DISABLED)
+            .sum(-1))
+        miss_steps += int((misses > 0).sum())
+        chunks += int((-(-np.minimum(misses, n_slots) // width)).sum())
+        steps += 1
+        if done:
+            break
+        if steps > 1:
+            reads_a_step.append(eng.stats.device_reads - reads)
+    assert eng.stats.hot_tier_miss_layer_steps == miss_steps > 0
+    assert eng.stats.hot_tier_fill_chunks == chunks >= miss_steps
+    # the reads of a step that admits and finishes nothing: the token,
+    # the cache lengths, four hot-tier counters and, with prefetch, two
+    # more
+    assert set(reads_a_step) == {8 if prefetch else 6}

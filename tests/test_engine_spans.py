@@ -102,8 +102,9 @@ def test_device_reads_count_every_copy_to_the_host(monkeypatch):
     assert counting.device_values == eng.stats.device_reads
     # per decode step: cache lengths, the sampled tokens, four hot-tier
     # and two speculation counters; per admission: the warm-up's scores
-    # and its count of inserts
-    assert eng.stats.device_reads == 8 * eng.stats.steps + 2 * len(reqs)
+    # and its count of inserts; per finished request: the hot tier's
+    # fill-chunk and miss counters
+    assert eng.stats.device_reads == 8 * eng.stats.steps + 3 * len(reqs)
 
 
 def test_ring_keeps_the_last_steps(monkeypatch):
